@@ -103,8 +103,9 @@ def _run_scale(report, n_clients: int):
     report(f"cluster/host_numpy_{scale}", us_host,
            "EMA readback + numpy kmeans/silhouette/KLD + host W")
     report(f"cluster/fused_jit_{scale}", us_dev, "2 dispatches, in-jit W")
+    mode = "interpret" if jax.default_backend() == "cpu" else "compiled"
     report(f"cluster/fused_kernel_{scale}", us_ker,
-           "pallas kmeans_assign + clustered_agg (interpret)")
+           f"pallas kmeans_assign + clustered_agg ({mode})")
     return us_host, min(us_dev, us_ker)
 
 

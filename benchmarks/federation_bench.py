@@ -8,13 +8,15 @@ hot spot of every federation round (Eq. 16). Reports warm wall-clock
 per round; ``bench/federation_round`` carries the headline
 fused-vs-legacy comparison for the perf trajectory.
 
-Sharded section: the forced host-device count is fixed at backend
-init, so each device count runs in its own subprocess
+Sharded section: on the CPU backend the forced host-device count is
+fixed at backend init, so each device count runs in its own subprocess
 (``python -m benchmarks.federation_bench --sharded-worker N`` under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N``) and reports
-its warm round time back on stdout. On this CPU container the shards
-share one physical socket — the numbers track dispatch/collective
-overhead of the shard_map path, not real multi-host scaling.
+its warm round time back on stdout; the shards share one physical
+socket, so those numbers track dispatch/collective overhead of the
+shard_map path, not multi-chip scaling. On an accelerator the section
+runs in process over the real devices (counts up to the visible
+device count), and every row names the platform it ran on.
 
 Chunked section (DESIGN.md §Chunk-streamed aggregation): a synthetic
 population-scale round — 2 profile groups over a 2-layer net with
@@ -109,21 +111,29 @@ def _run_dense_vs_legacy(report):
 
     scale = f"{N_CLIENTS}c_{n_params/1e6:.1f}Mp"
     report(f"federation/fused_jnp_{scale}", us_fused, "1 jit/net")
+    mode = "interpret" if jax.default_backend() == "cpu" else "compiled"
     report(f"federation/fused_kernel_{scale}", us_kernel,
-           "1 pallas_call/net (interpret)")
+           f"1 pallas_call/net ({mode})")
     report(f"federation/legacy_loop_{scale}", us_legacy,
            "per-(layer,cluster,leaf) dispatches")
     best = min(us_fused, us_kernel)
     report("bench/federation_round", best,
            f"legacy={us_legacy:.0f}us speedup={us_legacy / best:.2f}x")
 
-    # --- sharded round at 1/2/4/8 forced host devices (subprocess per
-    # count: the device-count flag binds at backend init)
-    for n in SHARDED_DEVICE_COUNTS:
-        us = _run_sharded_worker(n)
+    # --- sharded round. On the CPU backend: 1/2/4/8 forced host
+    # devices, one subprocess per count (the device-count flag binds at
+    # backend init). On an accelerator: in process, over the real
+    # devices — forced-CPU children would measure the host instead.
+    cpu = jax.default_backend() == "cpu"
+    counts = (SHARDED_DEVICE_COUNTS if cpu else
+              [n for n in SHARDED_DEVICE_COUNTS if n <= jax.device_count()])
+    for n in counts:
+        us = _run_sharded_worker(n) if cpu else _sharded_round_us(n)
         derived = ("single-device fallback (mesh of 1)" if n == 1 else
                    f"shard_map+psum, {N_CLIENTS // n} client rows/shard")
-        report(f"federation/sharded_round_{n}dev_{scale}", us, derived)
+        platform = "forced-cpu" if cpu else jax.devices()[0].platform
+        report(f"federation/sharded_round_{n}dev_{scale}", us,
+               f"{derived}; {platform}")
 
 
 # ---------------------------------------------------------------------------
@@ -223,17 +233,22 @@ def _run_sharded_worker(n_devices: int) -> float:
                        f"{proc.stdout}")
 
 
-def _sharded_worker_main(n_devices: int) -> None:
+def _sharded_round_us(n_devices: int) -> float:
+    """Warm round time on a federation mesh of the first ``n_devices``
+    visible devices."""
     from repro.launch.mesh import make_federation_mesh
-    assert jax.device_count() == n_devices, \
-        f"worker saw {jax.device_count()} devices, wanted {n_devices}"
     groups, params, _, weights, labels = _round_inputs()
     mesh = make_federation_mesh(n_devices)
     plans = {}
-    us = _bench(lambda: federate_client_params(
+    return _bench(lambda: federate_client_params(
         groups, params, weights, labels, n_layers=N_LAYERS,
         plan_cache=plans, mesh=mesh), iters=3)
-    print(f"SHARDED_US={us}", flush=True)
+
+
+def _sharded_worker_main(n_devices: int) -> None:
+    assert jax.device_count() == n_devices, \
+        f"worker saw {jax.device_count()} devices, wanted {n_devices}"
+    print(f"SHARDED_US={_sharded_round_us(n_devices)}", flush=True)
 
 
 if __name__ == "__main__":

@@ -1,8 +1,9 @@
-"""Kernel micro-benchmarks: interpret-mode Pallas vs pure-jnp oracle.
+"""Kernel micro-benchmarks: Pallas kernels vs the pure-jnp oracle.
 
-On CPU the numbers characterize the *oracle* path (the Pallas bodies run
-interpreted); on TPU re-run with REPRO_PALLAS_COMPILE=1 for real kernel
-timings. Reported as name,us_per_call,derived-GB/s.
+The backend picks the kernel mode: on the CPU backend (tests, this
+container) the Pallas bodies run interpreted, so the numbers
+characterize the interpreter, not a kernel; on TPU the same command
+times the compiled kernels. Reported as name,us_per_call,derived-GB/s.
 """
 from __future__ import annotations
 

@@ -69,6 +69,8 @@ def main() -> None:
                     help="federation section: chunk-streamed round only, "
                          "at 256 clients (CI smoke)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     rows = []
 

@@ -192,10 +192,17 @@ def canonicalize_labels_jax(labels: jnp.ndarray, num_clusters: int
     return rank[labels].astype(labels.dtype)
 
 
+# Distance and centre matmuls run at HIGHEST precision: TPU's default
+# f32 matmul is one bf16 pass, which at F = 6272 moves near-tied
+# distances enough to flip labels and the selected k.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
 def _sq_dists(x: jnp.ndarray, centers: jnp.ndarray) -> jnp.ndarray:
     """[N, M] squared euclidean distances, clipped at 0."""
+    xc = jnp.matmul(x, centers.T, precision=_HIGHEST)
     d2 = (jnp.sum(x * x, -1)[:, None]
-          - 2.0 * x @ centers.T + jnp.sum(centers * centers, -1)[None, :])
+          - 2.0 * xc + jnp.sum(centers * centers, -1)[None, :])
     return jnp.maximum(d2, 0.0)
 
 
@@ -206,7 +213,7 @@ def _assign(x: jnp.ndarray, centers: jnp.ndarray,
     if use_kernel:
         from repro.kernels import ops as kops
         return kops.kmeans_assign(x, centers)
-    scores = (-2.0 * x @ centers.T
+    scores = (-2.0 * jnp.matmul(x, centers.T, precision=_HIGHEST)
               + jnp.sum(centers * centers, -1)[None, :])
     return jnp.argmin(scores, axis=1).astype(jnp.int32)
 
@@ -266,7 +273,7 @@ def kmeans_jax(x: jnp.ndarray, k: int, key: jnp.ndarray, *,
         done = (it > 0) & jnp.all(new_labels == labels)
         onehot = jax.nn.one_hot(new_labels, k, dtype=x.dtype)    # [N, k]
         counts = onehot.sum(0)                                   # [k]
-        sums = onehot.T @ x                                      # [k, D]
+        sums = jnp.matmul(onehot.T, x, precision=_HIGHEST)      # [k, D]
         new = jnp.where(counts[:, None] > 0,
                         sums / jnp.maximum(counts, 1.0)[:, None], centers)
         # empty-cluster re-seed: farthest points from the *updated*
@@ -296,10 +303,14 @@ def silhouette_jax(x: jnp.ndarray, labels: jnp.ndarray,
     ``num_clusters`` is the static id bound. Returns a f32 scalar,
     -1.0 when fewer than two clusters appear or n < 3."""
     n = x.shape[0]
-    d = jnp.sqrt(_sq_dists(x, x))
+    # centring shrinks the norms whose difference the Gram expansion
+    # takes; the self-distance is exactly 0, not a rounding residue
+    xc = x - x.mean(0)
+    d2 = jnp.where(jnp.eye(n, dtype=bool), 0.0, _sq_dists(xc, xc))
+    d = jnp.sqrt(d2)
     onehot = jax.nn.one_hot(labels, num_clusters, dtype=x.dtype)  # [N, C]
     counts = onehot.sum(0)                                        # [C]
-    sums = d @ onehot                                             # [N, C]
+    sums = jnp.matmul(d, onehot, precision=_HIGHEST)              # [N, C]
     own = counts[labels]                                          # [N]
     a = sums[jnp.arange(n), labels] / jnp.maximum(own - 1.0, 1.0)
     mean_c = jnp.where(counts[None, :] > 0,

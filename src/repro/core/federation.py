@@ -73,7 +73,6 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.splitting import (ProfileGroup, bucket_size,
@@ -85,6 +84,7 @@ from repro.sharding.policy import client_axes, group_client_axes
 # the silhouette-selected k changes round to round) and stays
 # sublane-aligned for the kernel.
 _SEGMENT_PAD = 8
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def weighted_average_stacked(stacked: Any, weights: jnp.ndarray,
@@ -97,8 +97,20 @@ def weighted_average_stacked(stacked: Any, weights: jnp.ndarray,
             lambda x: kops.weighted_agg(x, weights), stacked)
     w = weights.astype(jnp.float32)
     return jax.tree_util.tree_map(
-        lambda x: jnp.einsum("k,k...->...", w, x.astype(jnp.float32)
-                             ).astype(x.dtype), stacked)
+        lambda x: jnp.einsum("k,k...->...", w, x.astype(jnp.float32),
+                             precision=_HIGHEST).astype(x.dtype), stacked)
+
+
+def _reduce_rows(A: jnp.ndarray, theta: jnp.ndarray,
+                 use_kernel: bool) -> jnp.ndarray:
+    """``A [S, k] @ theta [k, D]`` — the Pallas ``clustered_agg`` body
+    behind ``use_kernel``, else one jnp matmul. Both run at HIGHEST
+    precision: TPU's default f32 matmul is a single bf16 pass, which
+    would round every averaged parameter to bf16."""
+    if use_kernel:
+        from repro.kernels import ops as kops
+        return kops.clustered_agg(A, theta)
+    return jnp.matmul(A, theta, precision=_HIGHEST)
 
 
 # ---------------------------------------------------------------------------
@@ -224,11 +236,7 @@ def _accumulate_chunks_padded(layout: _ChunkedLayout, net_params,
             if S > Lpos * C:
                 ablocks.append(jnp.zeros((S - Lpos * C, c), jnp.float32))
             A_c = jnp.concatenate(ablocks, axis=0)           # [S, c]
-            if layout.use_kernel:
-                from repro.kernels import ops as kops
-                part = kops.clustered_agg(A_c, theta_c)
-            else:
-                part = A_c @ theta_c
+            part = _reduce_rows(A_c, theta_c, layout.use_kernel)
             return (acc + part.astype(jnp.float32),
                     mass + A_c.sum(1)), None
 
@@ -459,6 +467,15 @@ class FederationPlan:
                             or not group_sizes
                             else group_client_axes(mesh, group_sizes))
 
+    @property
+    def shard_axes(self):
+        """Mesh axes this plan's round reduces over — the chunk
+        stream's when built with ``chunk_size`` — or None when the
+        round runs on one device (no mesh, or the divisibility check
+        fell back)."""
+        return (self._chunk_axes if self.chunk_size is not None
+                else self._client_axes)
+
     # -- host-side weight matrix (Eq. 15/16 block diagonal) ----------------
     def weight_segments(self, weights: np.ndarray, cluster_labels: np.ndarray
                         ) -> Tuple[np.ndarray, np.ndarray]:
@@ -601,12 +618,7 @@ class FederationPlan:
         """(A [S, K], theta [K, D]) -> replicated agg [S, D] f32."""
         if self._client_axes is None:
             # single-device / fallback path: one full-K contraction.
-            def reduce(A, theta):
-                if use_kernel:
-                    from repro.kernels import ops as kops
-                    return kops.clustered_agg(A, theta)
-                return A @ theta
-            return reduce
+            return lambda A, theta: _reduce_rows(A, theta, use_kernel)
 
         # Sharded path: theta rows and A columns split over the client
         # axis; each shard contracts its local row block (the Pallas
@@ -620,18 +632,14 @@ class FederationPlan:
         axis_names = (axes,) if isinstance(axes, str) else tuple(axes)
 
         def local_partial(a_blk, theta_blk):
-            if use_kernel:
-                from repro.kernels import ops as kops
-                part = kops.clustered_agg(a_blk, theta_blk)
-            else:
-                part = a_blk @ theta_blk
+            part = _reduce_rows(a_blk, theta_blk, use_kernel)
             return jax.lax.psum(part.astype(jnp.float32), axis_names)
 
-        # check_rep=False: pallas_call has no shard_map replication
+        # check_vma=False: pallas_call has no shard_map replication
         # rule; the out_spec below is still fully replicated (psum).
-        return shard_map(local_partial, mesh=self.mesh,
-                         in_specs=(P(None, axes), P(axes, None)),
-                         out_specs=P(None, None), check_rep=False)
+        return jax.shard_map(local_partial, mesh=self.mesh,
+                             in_specs=(P(None, axes), P(axes, None)),
+                             out_specs=P(None, None), check_vma=False)
 
     def _make_agg_fn(self, use_kernel: bool, donate: bool,
                      with_cohort: bool = False) -> Callable:
@@ -794,11 +802,7 @@ class FederationPlan:
                     ablocks.append(jnp.zeros((S - Lpos * C, c),
                                              jnp.float32))
                 A_c = jnp.concatenate(ablocks, axis=0)           # [S, c]
-                if use_kernel:
-                    from repro.kernels import ops as kops
-                    part = kops.clustered_agg(A_c, theta_c)
-                else:
-                    part = A_c @ theta_c
+                part = _reduce_rows(A_c, theta_c, use_kernel)
                 return (acc + part.astype(jnp.float32),
                         mass + A_c.sum(1)), None
 
@@ -848,7 +852,7 @@ class FederationPlan:
                 # group's leaf stack (and the matching cids slice),
                 # scans its local chunks, and one psum merges the tiny
                 # (acc, mass) — same collective shape as the dense
-                # sharded reduction. check_rep=False: pallas_call has
+                # sharded reduction. check_vma=False: pallas_call has
                 # no shard_map replication rule.
                 def local(net_p, cids_l, w, lab, pt, zs):
                     a, m = self._accumulate_chunks(
@@ -860,12 +864,12 @@ class FederationPlan:
                     lambda x: P(axes, *([None] * (x.ndim - 1))),
                     net_params)
                 c_specs = {g: P(axes) for g in cids}
-                acc, mass = shard_map(
+                acc, mass = jax.shard_map(
                     local, mesh=self.mesh,
                     in_specs=(p_specs, c_specs, P(None), P(None),
                               P(None), P(None)),
                     out_specs=(P(None, None), P(None)),
-                    check_rep=False)(net_params, cids, w_all, lab_all,
+                    check_vma=False)(net_params, cids, w_all, lab_all,
                                      part, zero_seg)
             agg = acc / jnp.maximum(mass, 1e-20)[:, None]
             seg_ids = seg_of_copy.astype(jnp.int32)

@@ -147,6 +147,11 @@ def label_weights(label_hists: np.ndarray, sizes: np.ndarray,
 # JAX twins (device-resident stage 4 — DESIGN.md §Device-resident clustering)
 # ---------------------------------------------------------------------------
 
+# One-hot segment sums run at HIGHEST precision: TPU's default f32
+# matmul is one bf16 pass, and beta = 150 amplifies the rounding of a
+# leave-one-out mean into the Eq.-15 weight logits.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 def cluster_klds_jax(P: jnp.ndarray, labels: jnp.ndarray,
                      num_clusters: int, eps: float = 1e-12) -> jnp.ndarray:
     """Traced twin of cluster_klds: Eq. (14) leave-one-out cluster mean
@@ -154,7 +159,7 @@ def cluster_klds_jax(P: jnp.ndarray, labels: jnp.ndarray,
     bound; singleton clusters score 0 like the numpy path."""
     onehot = jax.nn.one_hot(labels, num_clusters, dtype=P.dtype)   # [K, C]
     counts = onehot.sum(0)                                         # [C]
-    csum = onehot.T @ P                                            # [C, F]
+    csum = jnp.matmul(onehot.T, P, precision=_HIGHEST)             # [C, F]
     own = counts[labels]                                           # [K]
     loo = (csum[labels] - P) / jnp.maximum(own - 1.0, 1.0)[:, None]
     p = jnp.clip(P, eps, None)
@@ -174,7 +179,7 @@ def federation_weights_jax(klds: jnp.ndarray, sizes: jnp.ndarray,
               - beta * klds.astype(jnp.float32))
     seg_max = jnp.where(onehot > 0, logits[:, None], -jnp.inf).max(0)  # [C]
     e = jnp.exp(logits - seg_max[labels])
-    denom = onehot.T @ e                                               # [C]
+    denom = jnp.matmul(onehot.T, e, precision=_HIGHEST)                # [C]
     return e / denom[labels]
 
 
@@ -198,7 +203,7 @@ def cohort_federation_weights_jax(klds: jnp.ndarray, sizes: jnp.ndarray,
     seg_max_safe = jnp.where(jnp.isfinite(seg_max), seg_max, 0.0)
     shifted = jnp.where(m, logits - seg_max_safe[labels], -jnp.inf)
     e = jnp.exp(shifted)                                           # [K]
-    denom = onehot.T @ e                                           # [C]
+    denom = jnp.matmul(onehot.T, e, precision=_HIGHEST)            # [C]
     d = denom[labels]
     return jnp.where(m & (d > 0), e / jnp.where(d > 0, d, 1.0), 0.0)
 

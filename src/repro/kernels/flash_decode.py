@@ -5,7 +5,8 @@ with S up to 524288. HBM-bandwidth-bound (the whole cache streams once
 per token), so the kernel's job is a single pass over S with an online
 softmax, never materializing the [S] score vector in HBM.
 
-TPU mapping: grid over (batch, S blocks); each step loads a
+TPU mapping: grid over (batch, S blocks), the cache length in SMEM as a
+scalar-prefetch operand; each step loads a
 [BLOCK_S, KV*hd] cache tile into VMEM, computes q.k on the MXU, and
 maintains running (max, denom, acc) f32 accumulators in VMEM scratch.
 GQA handled by grouping H = KV * G query heads per kv head. The final
@@ -23,7 +24,12 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_S = 512
+# VMEM budget for one [block_s, KV, hd] cache tile: k and v are
+# double-buffered, so four tiles plus the f32 score/prob temporaries
+# must fit the scoped VMEM of a v5e core (16 MiB).
+TILE_BYTES = 512 * 1024
 NEG_INF = -1e30
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -34,6 +40,7 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
     q_ref [1, KV, G, hd]; k_ref/v_ref [1, block_s, KV, hd];
     o_ref [1, KV, G, hd]; scratch: acc [KV, G, hd], m/d [KV, G, 128].
     """
+    b = pl.program_id(0)
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -46,9 +53,10 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
     k = k_ref[0].astype(jnp.float32)                     # [S_blk, KV, hd]
     v = v_ref[0].astype(jnp.float32)
     hd = q.shape[-1]
-    s = jnp.einsum("kgh,skh->kgs", q, k) / math.sqrt(hd)  # [KV, G, S_blk]
+    s = jnp.einsum("kgh,skh->kgs", q, k,
+                   precision=_HIGHEST) / math.sqrt(hd)    # [KV, G, S_blk]
     pos = j * block_s + jnp.arange(block_s)
-    valid = pos < len_ref[0]
+    valid = pos < len_ref[b]
     s = jnp.where(valid[None, None, :], s, NEG_INF)
 
     m_prev = m_ref[:, :, 0]                               # [KV, G]
@@ -57,7 +65,7 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
     p = jnp.exp(s - m_new[..., None])                     # [KV, G, S_blk]
     p = jnp.where(valid[None, None, :], p, 0.0)
     acc_ref[...] = acc_ref[...] * corr[..., None] + \
-        jnp.einsum("kgs,skh->kgh", p, v)
+        jnp.einsum("kgs,skh->kgh", p, v, precision=_HIGHEST)
     d_ref[:, :, 0] = d_ref[:, :, 0] * corr + jnp.sum(p, axis=-1)
     m_ref[:, :, 0] = m_new
 
@@ -70,11 +78,15 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                  cache_len: jnp.ndarray, *, block_s: int = BLOCK_S,
                  interpret: bool = True) -> jnp.ndarray:
-    """q [B, H, hd]; k/v [B, S, KV, hd]; cache_len scalar -> [B, H, hd]."""
+    """q [B, H, hd]; k/v [B, S, KV, hd]; cache_len scalar -> [B, H, hd].
+
+    ``block_s`` is capped so one cache tile stays within TILE_BYTES
+    (a multiple of 8 rows), then at S."""
     B, H, hd = q.shape
     S, KV = k.shape[1], k.shape[2]
     G = H // KV
-    block_s = min(block_s, S)
+    fit = TILE_BYTES // (KV * hd * k.dtype.itemsize)
+    block_s = min(block_s, max(8, fit // 8 * 8), S)
     S_pad = -(-S // block_s) * block_s
     kp = jnp.pad(k, ((0, 0), (0, S_pad - S), (0, 0), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, S_pad - S), (0, 0), (0, 0)))
@@ -86,20 +98,24 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     out = pl.pallas_call(
         functools.partial(_flash_decode_kernel, block_s=block_s,
                           n_blocks=n_blocks),
-        grid=(B, n_blocks),
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, j: (b,)),
-            pl.BlockSpec((1, KV, G, hd), lambda b, j: (b, 0, 0, 0)),
-            pl.BlockSpec((1, block_s, KV, hd), lambda b, j: (b, j, 0, 0)),
-            pl.BlockSpec((1, block_s, KV, hd), lambda b, j: (b, j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, KV, G, hd), lambda b, j: (b, 0, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, n_blocks),
+            in_specs=[
+                pl.BlockSpec((1, KV, G, hd), lambda b, j, lens: (b, 0, 0, 0)),
+                pl.BlockSpec((1, block_s, KV, hd),
+                             lambda b, j, lens: (b, j, 0, 0)),
+                pl.BlockSpec((1, block_s, KV, hd),
+                             lambda b, j, lens: (b, j, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, KV, G, hd),
+                                   lambda b, j, lens: (b, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((KV, G, hd), jnp.float32),
+                pltpu.VMEM((KV, G, 128), jnp.float32),
+                pltpu.VMEM((KV, G, 128), jnp.float32),
+            ]),
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((KV, G, hd), jnp.float32),
-            pltpu.VMEM((KV, G, 128), jnp.float32),
-            pltpu.VMEM((KV, G, 128), jnp.float32),
-        ],
         interpret=interpret,
     )(lens, qh, kp, vp)
     return out.reshape(B, H, hd)

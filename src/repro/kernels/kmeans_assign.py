@@ -9,6 +9,8 @@ TPU mapping: N is tiled into 128-row VMEM blocks (MXU-aligned); centers
 to ~8k fits VMEM). The ||x||^2 term is constant under argmin and
 dropped, so each block is one matmul on the MXU plus a VPU argmin:
     d2[n, m] ~ -2 x.c^T + ||c||^2.
+The matmul runs at HIGHEST precision: at D ~ 6k a one-pass bf16
+product moves near-tied distances enough to flip labels.
 """
 from __future__ import annotations
 
@@ -23,8 +25,10 @@ def _kmeans_kernel(x_ref, c_ref, o_ref):
     """x_ref [ROWS, D]; c_ref [M, D]; o_ref [ROWS, 1] int32."""
     x = x_ref[...].astype(jnp.float32)
     c = c_ref[...].astype(jnp.float32)
-    scores = -2.0 * jnp.dot(x, c.T, preferred_element_type=jnp.float32) \
-        + jnp.sum(c * c, axis=-1)[None, :]
+    xc = jax.lax.dot_general(x, c, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=jnp.float32)
+    scores = -2.0 * xc + jnp.sum(c * c, axis=-1)[None, :]
     o_ref[:, 0] = jnp.argmin(scores, axis=1).astype(jnp.int32)
 
 

@@ -24,7 +24,9 @@ VMEM blocks (sublane x lane aligned); the weight matrix stays resident
 per block so each block is one [S, K] x [K, 8*1024] contraction on the
 MXU/VPU — arithmetic intensity is low (streaming reduction), so the
 kernel is HBM-bandwidth-bound and the tiling keeps aligned 2D tiles
-streaming through VMEM exactly once.
+streaming through VMEM exactly once. The contraction runs at HIGHEST
+precision: averaged parameters must keep f32 accuracy, and the extra
+MXU passes hide under the HBM stream.
 
 Sharded rounds (repro.core.federation, ``mesh=``) invoke the same
 kernel *per shard* inside a ``shard_map`` over the client axis: each
@@ -32,7 +34,7 @@ device's block sees only its local ``[K/n, D]`` row slice of theta
 (and the matching ``[S, K/n]`` column slice of ``W``), computes the
 local partial aggregate, and the cross-device ``psum`` happens outside
 the kernel — the kernel body is oblivious to the mesh, K is simply
-smaller.  (shard_map needs ``check_rep=False`` around pallas_call;
+smaller.  (shard_map needs ``check_vma=False`` around pallas_call;
 the caller handles that.)
 
 ``block_tiles`` groups several (8, 1024) tiles into one grid step.  On
@@ -62,6 +64,7 @@ def _clustered_agg_kernel(w_ref, x_ref, o_ref):
     K = x.shape[0]
     agg = jax.lax.dot_general(w, x.reshape(K, -1),
                               (((1,), (0,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
     o_ref[...] = agg.reshape((w.shape[0],) + x.shape[1:])
 

@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.core.federation import donate_default
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 
 
@@ -28,6 +29,7 @@ def main(argv=None):
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     key = jax.random.PRNGKey(args.seed)

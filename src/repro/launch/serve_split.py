@@ -58,9 +58,11 @@ from repro.core.segments import (SplitProgram, compile_split_program,
 from repro.core.splitting import (ProfileGroup, bucket_size,
                                   group_by_profile, server_union_span)
 from repro.kernels import ops, ref
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import attention as A
 from repro.models import nn
-from repro.models.gan import GEN_LAYER_DEFS, DISC_LAYER_DEFS, Z_DIM
+from repro.models.gan import (DISC_LAYER_DEFS, GEN_LAYER_DEFS, Z_DIM,
+                              generator_forward)
 from repro.sharding.policy import (ShardingPolicy, activation_sharding,
                                    cohort_axes)
 
@@ -203,6 +205,28 @@ class SplitGanEngine:
                   for g, b in zip(active, buckets)}
         return program_forward_latency(program, profiles, server,
                                        batch=1, counts=counts)
+
+
+def monolithic_forward(groups: Sequence[ProfileGroup],
+                       client: Dict[str, Any], server: Dict[str, Any],
+                       req: ServeRequest) -> np.ndarray:
+    """The engine's oracle: assemble the requesting client's full
+    generator (its personal head/tail rows + the server's middle
+    layers) and run it unsplit on one request -> [28, 28, 1]."""
+    g = next(gg for gg in groups if req.client_id in gg.client_ids)
+    row = g.client_ids.index(req.client_id)
+    h, t = g.cut.g_h, g.cut.g_t
+    params = []
+    for l in range(len(GEN_LAYER_DEFS)):
+        if l < h or l >= t:
+            params.append(jax.tree_util.tree_map(
+                lambda x: x[row], client[g.name][str(l)]))
+        else:
+            params.append(server[str(l)])
+    z = jnp.asarray(req.z)[None]
+    y = jnp.asarray([req.y], jnp.int32)
+    img, _ = generator_forward(params, z, y, train=False)
+    return np.asarray(img[0])
 
 
 def init_gan_serving_state(key, groups: Sequence[ProfileGroup],
@@ -486,6 +510,7 @@ def main(argv=None):
     ap.add_argument("--gen", type=int, default=16)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.mode == "gan":
         _run_gan(args)
     else:

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def train_huscf_gan(args) -> None:
     from repro.core import HuSCFConfig, HuSCFTrainer, PAPER_DEVICES
@@ -148,6 +150,7 @@ def main(argv=None):
                          "static cuts)")
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.arch == "huscf-gan":
         train_huscf_gan(args)
     else:

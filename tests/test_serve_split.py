@@ -18,6 +18,7 @@ from repro.launch.serve_split import (ServeRequest, SplitGanEngine,
                                       SplitLMConfig, build_mix,
                                       init_gan_serving_state, init_split_lm,
                                       lm_reference_logits,
+                                      monolithic_forward,
                                       split_lm_decode_logits,
                                       split_lm_generate)
 from repro.models import gan
@@ -40,25 +41,6 @@ def _mk_requests(groups, n, seed=0):
             for _ in range(n)]
 
 
-def _monolithic_forward(groups, client, server, req):
-    """The oracle: assemble THIS client's full generator (its personal
-    head/tail rows + the server's middle layers) and run it unsplit."""
-    g = next(gg for gg in groups if req.client_id in gg.client_ids)
-    row = g.client_ids.index(req.client_id)
-    h, t = g.cut.g_h, g.cut.g_t
-    params = []
-    for l in range(gan.GEN_LAYERS):
-        if l < h or l >= t:
-            params.append(jax.tree_util.tree_map(
-                lambda x: x[row], client[g.name][str(l)]))
-        else:
-            params.append(server[str(l)])
-    z = jnp.asarray(req.z)[None]
-    y = jnp.asarray([req.y], jnp.int32)
-    img, _ = gan.generator_forward(params, z, y, train=False)
-    return np.asarray(img[0])
-
-
 @pytest.mark.parametrize("mix", MIXES)
 def test_engine_matches_monolithic_per_client(mix):
     engine, groups = _mk_engine(mix)
@@ -66,7 +48,7 @@ def test_engine_matches_monolithic_per_client(mix):
     imgs = engine.serve(reqs)
     assert imgs.shape == (11, 28, 28, 1)
     for i, req in enumerate(reqs):
-        want = _monolithic_forward(groups, engine.client_params,
+        want = monolithic_forward(groups, engine.client_params,
                                    engine.server_params, req)
         np.testing.assert_allclose(imgs[i], want, atol=1e-5, rtol=1e-5)
 
@@ -123,7 +105,7 @@ def test_engine_subset_cohort_drops_absent_cuts():
     h, t = g0.cut.g_h, g0.cut.g_t
     assert program.server_span() == tuple(range(h, t))
     imgs = engine.serve(reqs)
-    want = _monolithic_forward(groups, engine.client_params,
+    want = monolithic_forward(groups, engine.client_params,
                                engine.server_params, reqs[0])
     np.testing.assert_allclose(imgs[0], want, atol=1e-5, rtol=1e-5)
 
