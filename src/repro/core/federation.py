@@ -75,6 +75,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import spans
 from repro.core.splitting import (ProfileGroup, bucket_size,
                                   client_owned_layers, layer_pair)
 from repro.sharding.policy import client_axes, group_client_axes
@@ -167,13 +168,23 @@ class _ChunkedLayout:
     with_cohort: bool
 
 
+def _jit_round(fn: Callable, donate: bool, cache: str) -> Callable:
+    """One aggregation program: ``fn`` jitted with its ops under the
+    ``round.aggregate`` scope, counted as a build of ``cache``."""
+    spans.count("programs_built." + cache)
+    return jax.jit(jax.named_scope(spans.SCOPE_AGGREGATE)(fn),
+                   donate_argnums=(0,) if donate else ())
+
+
 _CHUNKED_FNS: Dict[Tuple[_ChunkedLayout, bool], Callable] = {}
 
 
 def _chunked_fn_cache_stats() -> Dict[str, int]:
-    """Test hook: number of shared chunked programs and their summed
-    jit-trace counts (cache stability across churn asserts on this)."""
-    return {"programs": len(_CHUNKED_FNS),
+    """Test hook: number of shared chunked programs built and their
+    summed jit-trace counts (cache stability across churn asserts on
+    this)."""
+    return {"programs": spans.counters().get("programs_built.agg_chunked",
+                                             0),
             "traces": sum(f._cache_size() for f in _CHUNKED_FNS.values())}
 
 
@@ -322,7 +333,7 @@ def _make_chunked_padded_fn(layout: _ChunkedLayout, donate: bool) -> Callable:
                w_all, lab_all):
             return run(net_params, cids, kg, copy_lpos, copy_cid,
                        copy_valid, w_all, lab_all)
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    return _jit_round(fn, donate, "agg_chunked")
 
 
 def _pad_rows(x: jnp.ndarray, b: int) -> jnp.ndarray:
@@ -661,7 +672,7 @@ class FederationPlan:
         else:
             def fn(net_params, A, seg_ids):
                 return self._unflatten(core(net_params, A, seg_ids), seg_ids)
-        return jax.jit(fn, donate_argnums=(0,) if donate else ())
+        return _jit_round(fn, donate, "agg")
 
     def aggregate(self, net_params: Dict[str, Dict[str, Any]],
                   A: np.ndarray, seg_ids: np.ndarray,
@@ -708,7 +719,7 @@ class FederationPlan:
             def fn(net_params, weights, labels):
                 agg, seg_ids = core(net_params, weights, labels)
                 return self._unflatten(agg, seg_ids)
-        return jax.jit(fn, donate_argnums=(0,) if donate else ())
+        return _jit_round(fn, donate, "agg")
 
     def aggregate_device(self, net_params: Dict[str, Dict[str, Any]],
                          weights: jnp.ndarray, labels: jnp.ndarray,
@@ -885,7 +896,7 @@ class FederationPlan:
         else:
             def fn(net_params, weights, labels):
                 return run(net_params, weights, labels)
-        return jax.jit(fn, donate_argnums=(0,) if donate else ())
+        return _jit_round(fn, donate, "agg")
 
     def _chunked_layout(self, num_clusters: int, use_kernel: bool,
                         with_cohort: bool) -> _ChunkedLayout:

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import spans
 from repro.core.latency import (Cut, DeviceProfile, PAPER_SERVER,
                                 all_cut_options, huscf_iteration_latency)
 from repro.core.latency_jax import (LatencyTables, build_latency_tables,
@@ -119,6 +120,7 @@ def _get_search_fn(pop_size: int, n_genes: int, n_opt: int,
     Lloyd iteration). Cached on the static GA shape so every device
     population with the same (pop, genes) reuses one compiled program
     — tables/counts arrive as arguments, not baked constants."""
+    spans.count("programs_built.ga")
     n_elite = max(0, min(elitism, pop_size - 1))
     n_child = pop_size - n_elite
     n_pairs = (n_child + 1) // 2
@@ -171,6 +173,7 @@ def _get_search_fn(pop_size: int, n_genes: int, n_opt: int,
         return (keys[0], pop, fits, best_ind, best_fit, conv, stall, gen,
                 hist)
 
+    @jax.named_scope(spans.SCOPE_GA)
     def search(key, tables: LatencyTables, counts=None) -> SearchOut:
         k_init, k_loop = jax.random.split(key)
         pop = jax.random.randint(k_init, (pop_size, n_genes), 0, n_opt,

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import kld as kld_mod
+from repro.core import spans
 from repro.core.clustering import (cluster_activations,
                                    cluster_activations_jax,
                                    k_selection_bound)
@@ -493,6 +494,7 @@ class HuSCFTrainer:
 
     # -- per-step path (correctness oracle, fused_epoch=False) -------------
     def _build_step(self) -> Callable:
+        spans.count("programs_built.step")
         core = self._step_core
         sample = self._sample
 
@@ -514,6 +516,7 @@ class HuSCFTrainer:
 
     # -- scan-fused device-resident epoch (fused_epoch=True) ---------------
     def _build_epoch(self, n_steps: int) -> Callable:
+        spans.count("programs_built.epoch")
         epoch = make_epoch_fn(self.groups, self._step_core, self._sample,
                               n_steps, unroll=self._epoch_unroll(n_steps))
         # donate the carry's state + EMA so Adam/param buffers update in
@@ -524,17 +527,23 @@ class HuSCFTrainer:
 
     # -- public API ----------------------------------------------------------
     def train_steps(self, n_steps: int) -> Dict[str, float]:
+        with spans.span("train_steps"):
+            return self._train_steps(n_steps)
+
+    def _train_steps(self, n_steps: int) -> Dict[str, float]:
         if self.cfg.fused_epoch:
             fn = self._epoch_fns.get(n_steps)
             if fn is None:
                 fn = self._epoch_fns[n_steps] = self._build_epoch(n_steps)
-            (self.state, self._train_key, self._mid_ema, self._ema_init,
-             metrics) = fn(self.state, self._dataset, self._train_key,
-                           self._mid_ema, self._ema_init)
+            with spans.span("train_steps.dispatch"):
+                (self.state, self._train_key, self._mid_ema, self._ema_init,
+                 metrics) = fn(self.state, self._dataset, self._train_key,
+                               self._mid_ema, self._ema_init)
             # only after the epoch dispatched: a failed first call must
             # leave the fused federate()'s empty-EMA guard armed
             self._trained = True
-            return {k: float(v[-1]) for k, v in metrics.items()}
+            with spans.span("train_steps.readback"):
+                return {k: float(v[-1]) for k, v in metrics.items()}
         # oracle: one dispatch per step, blocking mid-activation
         # readback + per-client Python EMA each step
         last = {}
@@ -604,6 +613,10 @@ class HuSCFTrainer:
         weights renormalize over the cohort and non-members keep their
         params. ``cfg.agg_chunk`` streams the aggregation in client
         chunks instead of the dense [K, D] buffer."""
+        with spans.span("federate"):
+            return self._federate(use_label_kld, mesh)
+
+    def _federate(self, use_label_kld: bool, mesh: Any) -> Dict[str, Any]:
         mesh = self.fed_mesh if mesh is self._MESH_DEFAULT else mesh
         self.fed_round += 1
         recut = None
@@ -612,33 +625,39 @@ class HuSCFTrainer:
             # online cut re-optimization: one cached-program GA
             # dispatch against the current profiles; regroups (and
             # invalidates the plan cache) only on strictly better cuts
-            recut = self.reoptimize_cuts()
+            with spans.span("federate.recut"):
+                recut = self.reoptimize_cuts()
         cohort_ids = cohort_mask = None
         if self.cfg.cohort_size is not None:
-            self._cohort_key, sub = jax.random.split(self._cohort_key)
-            cohort_ids = self.registry.sample_cohort(sub,
-                                                     self.cfg.cohort_size)
-            cohort_mask = self.registry.cohort_mask(cohort_ids)
+            with spans.span("federate.cohort"):
+                self._cohort_key, sub = jax.random.split(self._cohort_key)
+                cohort_ids = self.registry.sample_cohort(
+                    sub, self.cfg.cohort_size)
+                cohort_mask = self.registry.cohort_mask(cohort_ids)
         if self.fed_round <= self.cfg.warmup_fed_rounds:
+            spans.count("rounds.fedavg")
             # host fedavg path: the tiny cohort mask is the one
             # readback (warmup rounds predate the device-resident chain
             # anyway — cohort-critical runs set warmup_fed_rounds=0)
-            mask_np = (None if cohort_mask is None
-                       else np.asarray(cohort_mask))
-            for net in ("G", "D"):
-                wrapped = {g.name: {net: self.state[net]["client"][g.name]}
-                           for g in self.groups}
-                # the trainer drops its references right below, so the
-                # round may donate the old client buffers (TPU/GPU)
-                out = fedavg_uniform(self.groups, wrapped, self.sizes,
-                                     n_layers={net: _N_LAYERS[net]},
-                                     use_kernel=self.cfg.use_kernel,
-                                     plan_cache=self._fed_plans,
-                                     donate=donate_default(), mesh=mesh,
-                                     chunk_size=self.cfg.agg_chunk,
-                                     cohort_mask=mask_np)
-                self.state[net]["client"] = {g.name: out[g.name][net]
-                                             for g in self.groups}
+            with spans.span("federate.fedavg"):
+                mask_np = (None if cohort_mask is None
+                           else np.asarray(cohort_mask))
+                for net in ("G", "D"):
+                    wrapped = {g.name: {net: self.state[net]["client"][
+                        g.name]} for g in self.groups}
+                    # the trainer drops its references right below, so
+                    # the round may donate the old client buffers
+                    # (TPU/GPU)
+                    out = fedavg_uniform(self.groups, wrapped, self.sizes,
+                                         n_layers={net: _N_LAYERS[net]},
+                                         use_kernel=self.cfg.use_kernel,
+                                         plan_cache=self._fed_plans,
+                                         donate=donate_default(),
+                                         mesh=mesh,
+                                         chunk_size=self.cfg.agg_chunk,
+                                         cohort_mask=mask_np)
+                    self.state[net]["client"] = {g.name: out[g.name][net]
+                                                 for g in self.groups}
             diag = {"round": self.fed_round, "mode": "fedavg"}
             if cohort_ids is not None:
                 diag["cohort"] = cohort_ids
@@ -646,42 +665,47 @@ class HuSCFTrainer:
                 diag["recut"] = recut
             return diag
 
+        spans.count("rounds.clustered")
         if self.cfg.fused_cluster and not use_label_kld:
             diag = self._federate_fused(mesh, cohort_ids, cohort_mask)
             if recut is not None:
                 diag["recut"] = recut
             return diag
 
-        acts = self.middle_activations()
-        cl = cluster_activations(acts, k=self.cfg.num_clusters,
-                                 seed=self.cfg.seed)
-        if use_label_kld:
-            hists = np.stack([np.bincount(c.labels, minlength=gan.NUM_CLASSES)
-                              for c in self.clients])
-            weights, klds = kld_mod.label_weights(hists, self.sizes,
-                                                  cl.labels, self.cfg.beta)
-        else:
-            weights, klds = kld_mod.activation_weights(acts, self.sizes,
-                                                       cl.labels, self.cfg.beta)
-        mask_np = None if cohort_mask is None else np.asarray(cohort_mask)
-        if mask_np is not None:
-            # KLDs stay full-cluster; only the Eq.-15 normalization
-            # restricts to the sampled participants.
-            weights = kld_mod.cohort_federation_weights(
-                klds, self.sizes, cl.labels, mask_np, self.cfg.beta)
-        for net in ("G", "D"):
-            wrapped = {g.name: {net: self.state[net]["client"][g.name]}
-                       for g in self.groups}
-            out = federate_client_params(self.groups, wrapped, weights,
-                                         cl.labels,
-                                         n_layers={net: _N_LAYERS[net]},
-                                         use_kernel=self.cfg.use_kernel,
-                                         plan_cache=self._fed_plans,
-                                         donate=donate_default(), mesh=mesh,
-                                         chunk_size=self.cfg.agg_chunk,
-                                         cohort_mask=mask_np)
-            self.state[net]["client"] = {g.name: out[g.name][net]
-                                         for g in self.groups}
+        with spans.span("federate.cluster"):
+            acts = self.middle_activations()
+            cl = cluster_activations(acts, k=self.cfg.num_clusters,
+                                     seed=self.cfg.seed)
+            if use_label_kld:
+                hists = np.stack([np.bincount(c.labels,
+                                              minlength=gan.NUM_CLASSES)
+                                  for c in self.clients])
+                weights, klds = kld_mod.label_weights(
+                    hists, self.sizes, cl.labels, self.cfg.beta)
+            else:
+                weights, klds = kld_mod.activation_weights(
+                    acts, self.sizes, cl.labels, self.cfg.beta)
+            mask_np = None if cohort_mask is None else np.asarray(cohort_mask)
+            if mask_np is not None:
+                # KLDs stay full-cluster; only the Eq.-15 normalization
+                # restricts to the sampled participants.
+                weights = kld_mod.cohort_federation_weights(
+                    klds, self.sizes, cl.labels, mask_np, self.cfg.beta)
+        with spans.span("federate.aggregate"):
+            for net in ("G", "D"):
+                wrapped = {g.name: {net: self.state[net]["client"][g.name]}
+                           for g in self.groups}
+                out = federate_client_params(self.groups, wrapped, weights,
+                                             cl.labels,
+                                             n_layers={net: _N_LAYERS[net]},
+                                             use_kernel=self.cfg.use_kernel,
+                                             plan_cache=self._fed_plans,
+                                             donate=donate_default(),
+                                             mesh=mesh,
+                                             chunk_size=self.cfg.agg_chunk,
+                                             cohort_mask=mask_np)
+                self.state[net]["client"] = {g.name: out[g.name][net]
+                                             for g in self.groups}
         diag = {"round": self.fed_round, "mode": "clustered",
                 "k": cl.k, "silhouette": cl.silhouette,
                 "labels": cl.labels, "weights": weights, "klds": klds}
@@ -692,32 +716,31 @@ class HuSCFTrainer:
         return diag
 
     # -- device-resident stage 3+4 (fused_cluster) -------------------------
-    def _get_cluster_fn(self, with_cohort: bool = False) -> Callable:
+    def _get_cluster_fn(self) -> Callable:
         """Jitted (acts, sizes, key[, cohort_mask]) -> (labels, k, sil,
         weights, klds) — stage 3+4 compute in one dispatch. Cached per
-        (beta, num_clusters, use_kernel, with_cohort) because
-        benchmarks mutate cfg fields between rounds."""
+        (beta, num_clusters, use_kernel) because benchmarks mutate cfg
+        fields between rounds."""
         key = (float(self.cfg.beta), self.cfg.num_clusters,
-               self.cfg.use_kernel, with_cohort)
+               self.cfg.use_kernel)
         fn = self._cluster_fns.get(key)
         if fn is None:
+            spans.count("programs_built.cluster")
             beta, k_cfg = float(self.cfg.beta), self.cfg.num_clusters
             use_kernel = self.cfg.use_kernel
 
             def cluster_weight(acts, sizes, key, cohort_mask=None):
-                labels, k_sel, sil = cluster_activations_jax(
-                    acts, key, k=k_cfg, use_kernel=use_kernel)
-                weights, klds = kld_mod.activation_weights_jax(
-                    acts, sizes, labels,
-                    k_selection_bound(acts.shape[0], k_cfg), beta,
-                    cohort_mask=cohort_mask)
+                with jax.named_scope(spans.SCOPE_CLUSTER):
+                    labels, k_sel, sil = cluster_activations_jax(
+                        acts, key, k=k_cfg, use_kernel=use_kernel)
+                with jax.named_scope(spans.SCOPE_KLD):
+                    weights, klds = kld_mod.activation_weights_jax(
+                        acts, sizes, labels,
+                        k_selection_bound(acts.shape[0], k_cfg), beta,
+                        cohort_mask=cohort_mask)
                 return labels, k_sel, sil, weights, klds
 
-            if with_cohort:
-                fn = jax.jit(lambda a, s, k, m: cluster_weight(a, s, k, m))
-            else:
-                fn = jax.jit(lambda a, s, k: cluster_weight(a, s, k))
-            self._cluster_fns[key] = fn
+            fn = self._cluster_fns[key] = jax.jit(cluster_weight)
         return fn
 
     def _federate_fused(self, mesh, cohort_ids=None,
@@ -735,30 +758,28 @@ class HuSCFTrainer:
             raise RuntimeError(
                 "federate() before any training step: the middle-"
                 "activation EMA is empty")
-        acts = (self._mid_ema if self.cfg.fused_epoch
-                else jnp.asarray(self.middle_activations()))
-        self._cluster_key, sub = jax.random.split(self._cluster_key)
-        if cohort_mask is not None:
-            labels, k_sel, sil, weights, klds = self._get_cluster_fn(
-                with_cohort=True)(acts, self._sizes_dev, sub, cohort_mask)
-        else:
+        with spans.span("federate.cluster"):
+            acts = (self._mid_ema if self.cfg.fused_epoch
+                    else jnp.asarray(self.middle_activations()))
+            self._cluster_key, sub = jax.random.split(self._cluster_key)
             labels, k_sel, sil, weights, klds = self._get_cluster_fn()(
-                acts, self._sizes_dev, sub)
+                acts, self._sizes_dev, sub, cohort_mask)
         bound = k_selection_bound(len(self.clients), self.cfg.num_clusters)
-        for net in ("G", "D"):
-            wrapped = {g.name: {net: self.state[net]["client"][g.name]}
-                       for g in self.groups}
-            out = federate_client_params_device(
-                self.groups, wrapped, weights, labels, bound,
-                n_layers={net: _N_LAYERS[net]},
-                use_kernel=self.cfg.use_kernel,
-                plan_cache=self._fed_plans,
-                donate=donate_default(), mesh=mesh,
-                chunk_size=self.cfg.agg_chunk,
-                cohort_mask=cohort_mask,
-                cohort_size=self.cfg.cohort_size)
-            self.state[net]["client"] = {g.name: out[g.name][net]
-                                         for g in self.groups}
+        with spans.span("federate.aggregate"):
+            for net in ("G", "D"):
+                wrapped = {g.name: {net: self.state[net]["client"][g.name]}
+                           for g in self.groups}
+                out = federate_client_params_device(
+                    self.groups, wrapped, weights, labels, bound,
+                    n_layers={net: _N_LAYERS[net]},
+                    use_kernel=self.cfg.use_kernel,
+                    plan_cache=self._fed_plans,
+                    donate=donate_default(), mesh=mesh,
+                    chunk_size=self.cfg.agg_chunk,
+                    cohort_mask=cohort_mask,
+                    cohort_size=self.cfg.cohort_size)
+                self.state[net]["client"] = {g.name: out[g.name][net]
+                                             for g in self.groups}
         diag = {"round": self.fed_round, "mode": "clustered",
                 "k": k_sel, "silhouette": sil, "labels": labels,
                 "weights": weights, "klds": klds}
@@ -808,6 +829,7 @@ class HuSCFTrainer:
                                           self.cfg.batch)
         if result.latency >= current * (1 - 1e-9):
             return False
+        spans.count("recuts.adopted")
         self.ga_latency = result.latency
         self._rebuild_population(self.clients, self.devices, result.cuts,
                                  old_of=list(range(len(self.clients))))
@@ -1003,6 +1025,7 @@ class HuSCFTrainer:
                  ) -> Tuple[np.ndarray, np.ndarray]:
         """Generate len(labels) images by cycling clients. labels [N]."""
         if self._gen_fn is None:
+            spans.count("programs_built.generate")
             build = (build_net_apply if self.cfg.split_program
                      else build_net_apply_legacy)
             gen_apply = build(self.groups, "G")

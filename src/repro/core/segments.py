@@ -46,6 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.latency import DeviceProfile, PAPER_SERVER
+from repro.core.spans import SCOPE_HEAD, SCOPE_TAIL, SCOPE_TRUNK
 from repro.core.splitting import (ProfileGroup, bucket_size, layer_pair,
                                   server_union_span)
 from repro.models.gan import (DISC_LAYER_COSTS, DISC_LAYER_DEFS,
@@ -213,6 +214,10 @@ def make_apply(program: SplitProgram, capture_middle: bool = False,
     middle = program.middle
 
     def apply(client_params, server_params, inputs, train: bool):
+        with jax.named_scope(program.net):
+            return _apply(client_params, server_params, inputs, train)
+
+    def _apply(client_params, server_params, inputs, train: bool):
         new_client = {name: dict(client_params[name])
                       for name in program.group_names}
         new_server = dict(server_params)
@@ -222,58 +227,63 @@ def make_apply(program: SplitProgram, capture_middle: bool = False,
         for seg in program.heads:
             head_fn = functools.partial(head_pass, defs, stop=seg.stop,
                                         train=train)
-            acts, upd = jax.vmap(lambda p, *xs: head_fn(p, xs))(
-                client_params[seg.gname], *inputs[seg.gname])
-            new_client[seg.gname].update(upd)
-            k, b = acts.shape[0], acts.shape[1]
-            shapes[seg.gname] = (k, b)
-            bufs[seg.gname] = maybe_shard(
-                acts.reshape((k * b,) + acts.shape[2:]), "rows")
+            with jax.named_scope(SCOPE_HEAD), jax.named_scope(seg.gname):
+                acts, upd = jax.vmap(lambda p, *xs: head_fn(p, xs))(
+                    client_params[seg.gname], *inputs[seg.gname])
+                new_client[seg.gname].update(upd)
+                k, b = acts.shape[0], acts.shape[1]
+                shapes[seg.gname] = (k, b)
+                bufs[seg.gname] = maybe_shard(
+                    acts.reshape((k * b,) + acts.shape[2:]), "rows")
         # --- server trunk: one ServerStep per layer, joins/departs
         #     resolved at compile time (paper Fig. 7)
         outs: Dict[str, Array] = {}
         middles: Dict[str, Array] = {}
         for step in program.steps:
             l = step.layer
-            if concat_groups:
-                xs = [bufs[gname] for gname in step.active]
-                sizes = [x.shape[0] for x in xs]
-                x = jnp.concatenate(xs, 0) if len(xs) > 1 else xs[0]
-                x, new_server[str(l)] = defs[l][1](server_params[str(l)], x,
-                                                   train)
-                parts = (jnp.split(x, list(np.cumsum(sizes)[:-1]), 0)
-                         if len(xs) > 1 else [x])
-            else:
-                # per-group pass through the SAME shared server weights;
-                # BN state updates merge by equal-weight averaging.
-                parts, bn_updates = [], []
-                for gname in step.active:
-                    y, upd = defs[l][1](server_params[str(l)],
-                                        bufs[gname], train)
-                    parts.append(y)
-                    bn_updates.append(upd)
-                new_server[str(l)] = jax.tree_util.tree_map(
-                    lambda *xs: sum(xs) / len(xs), *bn_updates)
-            for gname, part in zip(step.active, parts):
-                bufs[gname] = maybe_shard(part, "rows")
-                if capture_middle and l == middle:
-                    k, b = shapes[gname]
-                    mid = part.reshape((k, b) + part.shape[1:])
-                    middles[gname] = jnp.mean(
-                        mid.reshape(k, b, -1).astype(jnp.float32), axis=1)
-                if gname in step.departs:
-                    outs[gname] = bufs[gname]
+            with jax.named_scope(SCOPE_TRUNK), jax.named_scope(f"layer{l}"):
+                if concat_groups:
+                    xs = [bufs[gname] for gname in step.active]
+                    sizes = [x.shape[0] for x in xs]
+                    x = jnp.concatenate(xs, 0) if len(xs) > 1 else xs[0]
+                    x, new_server[str(l)] = defs[l][1](
+                        server_params[str(l)], x, train)
+                    parts = (jnp.split(x, list(np.cumsum(sizes)[:-1]), 0)
+                             if len(xs) > 1 else [x])
+                else:
+                    # per-group pass through the SAME shared server
+                    # weights; BN state updates merge by equal-weight
+                    # averaging.
+                    parts, bn_updates = [], []
+                    for gname in step.active:
+                        y, upd = defs[l][1](server_params[str(l)],
+                                            bufs[gname], train)
+                        parts.append(y)
+                        bn_updates.append(upd)
+                    new_server[str(l)] = jax.tree_util.tree_map(
+                        lambda *xs: sum(xs) / len(xs), *bn_updates)
+                for gname, part in zip(step.active, parts):
+                    bufs[gname] = maybe_shard(part, "rows")
+                    if capture_middle and l == middle:
+                        k, b = shapes[gname]
+                        mid = part.reshape((k, b) + part.shape[1:])
+                        middles[gname] = jnp.mean(
+                            mid.reshape(k, b, -1).astype(jnp.float32),
+                            axis=1)
+                    if gname in step.departs:
+                        outs[gname] = bufs[gname]
         # --- client tails (vmapped)
         results: Dict[str, Array] = {}
         for seg in program.tails:
-            k, b = shapes[seg.gname]
-            x = outs[seg.gname]
-            x = x.reshape((k, b) + x.shape[1:])
-            tail_fn = functools.partial(tail_pass, defs, start=seg.start,
-                                        n=n, train=train)
-            y, upd = jax.vmap(tail_fn)(client_params[seg.gname], x)
-            new_client[seg.gname].update(upd)
-            results[seg.gname] = y
+            with jax.named_scope(SCOPE_TAIL), jax.named_scope(seg.gname):
+                k, b = shapes[seg.gname]
+                x = outs[seg.gname]
+                x = x.reshape((k, b) + x.shape[1:])
+                tail_fn = functools.partial(tail_pass, defs, start=seg.start,
+                                            n=n, train=train)
+                y, upd = jax.vmap(tail_fn)(client_params[seg.gname], x)
+                new_client[seg.gname].update(upd)
+                results[seg.gname] = y
         return results, new_client, new_server, middles
 
     return apply
