@@ -21,13 +21,13 @@ device count), and every row names the platform it ran on.
 Chunked section (DESIGN.md §Chunk-streamed aggregation): a synthetic
 population-scale round — 2 profile groups over a 2-layer net with
 n_cols ~ 8192 — at 1k and 8k clients, streamed in chunks of 256. The
-headline is the memory claim, not wall clock: the dense paths
-materialize a ``theta [K, D]`` f32 buffer (`dense_buffer_bytes`) that
-grows with the client count, while the chunk stream's working set
-(`chunked_buffer_bytes`) is O(chunk + clusters). Against a 128 MB
-working-set envelope the 8k dense buffer (256 MB) does not fit, so its
-wall clock is skipped and only the chunked round reports; at 1k both
-run and the dense round is the wall-clock baseline. ``tiny=True``
+headline is the memory claim, not wall clock: the dense round reads
+every owned client row and writes as many anew (`dense_buffer_bytes`),
+which grows with the client count, while the chunk stream's working
+set (`chunked_buffer_bytes`) is O(chunk + clusters). Against a 128 MB
+working-set envelope the 8k dense rows do not fit, so their wall clock
+is skipped and only the chunked round reports; at 1k both run and the
+dense round is the wall-clock baseline. ``tiny=True``
 (ci_smoke) keeps a 256-client / d=512 / chunk-64 variant of just this
 section.
 """

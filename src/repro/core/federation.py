@@ -10,32 +10,37 @@ stream (see DESIGN.md §7 for the interpretation of the paper's global
 Eq. 16 on shared parameters).
 
 Fused round (DESIGN.md §Fused federation): a cached ``FederationPlan``
-packs every profile group's stacked client segments into one
-contiguous ``theta [K, D]`` f32 buffer per net (one row per client
-copy, one column run per ownable layer, zero-filled where a cut does
-not own the layer), builds the block-diagonal Eq.-15/16 weight matrix
-on the host — one block per (layer, cluster), one row per receiving
-client copy, factored exactly as ``W = B @ A`` with ``A [S, K]`` the
-per-segment reduce rows and ``B`` the one-hot broadcast — and runs
-flatten -> A @ theta -> broadcast-gather -> unflatten as a single
-jitted computation, one Pallas ``clustered_agg`` call per net when
-``use_kernel=True``. Treedefs, leaf shapes, and layer/row offsets are
-cached on the plan so repeat rounds do zero host-side tree walking.
-The original quadruple loop (net x layer x cluster x member) is kept
-as the correctness oracle behind ``fused=False``.
+builds the block-diagonal Eq.-15/16 weight matrix — one block per
+(layer, cluster), one row per receiving client copy, factored exactly
+as ``W = B @ A`` with ``A [S, K]`` the per-segment reduce rows and
+``B`` the one-hot broadcast — and runs the round leaf by leaf in the
+layout the client state is stored in, as a single jitted computation
+per net: every leaf of a client-ownable layer is contracted over its
+owners' stacked client axis with the layer's block of ``A``, group by
+group (``[C, k_g] @ [k_g, *leaf]``, summed; the Pallas
+``clustered_agg`` kernel when ``use_kernel=True`` and the leaf fills
+whole lane tiles), and each owning group's new leaf is its rows of the
+``[C, *leaf]`` aggregate gathered along the client axis. No flat
+``[K, D]`` buffer, zero-filled columns or ``[S, D]`` output exists:
+each client row is read once and written once. Treedefs, leaf shapes,
+and row offsets are cached on the plan so repeat rounds do zero
+host-side tree walking. The original quadruple loop (net x layer x
+cluster x member) is kept as the correctness oracle behind
+``fused=False``.
 
 Sharded round (DESIGN.md §Sharded federation): with ``mesh=`` given,
-``theta``'s client (row) axis shards over the mesh's ('pod', 'data')
-axes — the same "rows" placement as every population-batch tensor —
-and the ``A @ theta`` cluster reduction runs as a ``shard_map``-ed
-local partial-sum (the Pallas ``clustered_agg`` kernel on each
-shard's row block) followed by a ``psum`` over the client axis, so
-every host ends the collective holding the replicated ``[S, D]``
-cluster means and ``_unflatten`` stays local. When the client count
-is not divisible by the mesh (``sharding.policy.client_axes``'s
-sanitize fallback) or the mesh has one device, the plan silently
-uses the single-device path; ``mesh=None`` (the default) is that
-path byte-for-byte.
+each group's stacked leaves shard their client axis over the mesh's
+('pod', 'data') axes — the same "rows" placement as every
+population-batch tensor — and the per-leaf contraction runs as a
+``shard_map``-ed local partial sum (the Pallas ``clustered_agg`` kernel
+on each shard's rows) followed by a ``psum`` of the small ``[C, ...]``
+aggregates, so every host ends the collective holding them replicated
+and the broadcast gather stays local. A group the mesh does not divide
+stays whole on every shard, each shard taking every n-th of its weight
+columns. When the client count is not divisible by the mesh
+(``sharding.policy.client_axes``'s sanitize fallback) or the mesh has
+one device, the plan silently uses the single-device path;
+``mesh=None`` (the default) is that path byte-for-byte.
 
 Device-resident round (DESIGN.md §Device-resident clustering): with
 stage 3+4 running on device (``clustering.cluster_activations_jax`` +
@@ -48,7 +53,7 @@ static ``k_selection_bound`` and never retraces as the selected k
 moves round to round.
 
 Chunk-streamed round (DESIGN.md §Chunk-streamed aggregation): with
-``chunk_size=`` the dense ``[K, D]`` buffer is never materialized —
+``chunk_size=`` the round holds O(chunk) client rows at a time —
 ``aggregate_chunked`` ``lax.scan``s each profile group's stacked rows
 in fixed-size chunks, contracting one ``A_c [S, c] @ theta_c [c, D]``
 tile per chunk (the Pallas ``clustered_agg`` kernel when
@@ -73,11 +78,13 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import spans
 from repro.core.splitting import (ProfileGroup, bucket_size,
                                   client_owned_layers, layer_pair)
+from repro.kernels import ops as kops
+from repro.kernels import weighted_agg as wa
 from repro.sharding.policy import client_axes, group_client_axes
 
 # Segment-count padding: round the number of (layer, cluster) blocks up
@@ -93,7 +100,6 @@ def weighted_average_stacked(stacked: Any, weights: jnp.ndarray,
     """Weighted sum over the leading client axis of every leaf.
     `weights` must already be normalized over that axis."""
     if use_kernel:
-        from repro.kernels import ops as kops
         return jax.tree_util.tree_map(
             lambda x: kops.weighted_agg(x, weights), stacked)
     w = weights.astype(jnp.float32)
@@ -109,9 +115,29 @@ def _reduce_rows(A: jnp.ndarray, theta: jnp.ndarray,
     precision: TPU's default f32 matmul is a single bf16 pass, which
     would round every averaged parameter to bf16."""
     if use_kernel:
-        from repro.kernels import ops as kops
         return kops.clustered_agg(A, theta)
     return jnp.matmul(A, theta, precision=_HIGHEST)
+
+
+def _contract_leaf(ws: Sequence[jnp.ndarray], xs: Sequence[jnp.ndarray],
+                   use_kernel: bool) -> jnp.ndarray:
+    """``sum_g ws[g] [C, k_g] @ xs[g] [k_g, *leaf]`` over the stacked
+    client axis -> ``[C, *leaf]`` f32, group by group (never
+    concatenated), at HIGHEST precision: the Pallas ``clustered_agg``
+    kernel when ``use_kernel`` and the leaf's shape fits it
+    (``weighted_agg.kernel_fits``), else one ``dot_general`` per group
+    on the same ``leaf_view``s."""
+    shape = tuple(xs[0].shape[1:])
+    if use_kernel and wa.kernel_fits(shape):
+        return kops.clustered_agg(ws, xs)
+    out = None
+    for w, x in zip(ws, xs):
+        v = x.reshape(wa.leaf_view(x.shape)).astype(jnp.float32)
+        part = jax.lax.dot_general(w, v, (((1,), (0,)), ((), ())),
+                                   precision=_HIGHEST,
+                                   preferred_element_type=jnp.float32)
+        out = part if out is None else out + part
+    return out.reshape((out.shape[0],) + shape)
 
 
 # ---------------------------------------------------------------------------
@@ -127,8 +153,9 @@ class _LeafSpec:
 
 @dataclasses.dataclass(frozen=True)
 class _SegmentEntry:
-    """One (group, layer) tile of the flat buffer: the group's rows x
-    the layer's column run."""
+    """One (group, layer) ownership: the group's rows, its receiving
+    copies' slice of ``seg_ids``, the layer's leaf specs, and the
+    layer's column run in the chunk stream's flat tile."""
     layer: int
     gname: str
     row0: int
@@ -347,33 +374,40 @@ def _pad_rows(x: jnp.ndarray, b: int) -> jnp.ndarray:
 
 
 class FederationPlan:
-    """Host-side flattening/aggregation plan for one (net, topology).
+    """Host-side aggregation plan for one (net, topology).
 
-    Flat layout: ``theta [K, D]`` — one row per client copy (groups in
-    canonical order), one contiguous column run per client-ownable
-    layer (zero-filled where a client's cut does not own the layer).
-    The Eq.-16 round is then ``W @ theta`` with the block-diagonal
-    per-(layer, cluster) weight matrix, factored exactly as
-    ``W = B @ A``: ``A [S, K]`` holds one normalized reduce row per
+    Rows: one per client copy, groups in canonical order; a group's
+    rows are contiguous, so its columns of the weight matrix are a
+    static slice. The Eq.-16 round is ``W @ theta`` with the
+    block-diagonal per-(layer, cluster) weight matrix, factored exactly
+    as ``W = B @ A``: ``A [S, K]`` holds one normalized reduce row per
     segment and the one-hot ``B`` broadcasts each segment's aggregate
-    back to every receiving copy (a gather on the [S, D] output,
-    restricted to that layer's columns — non-member columns of an
-    ``A`` row are never read).
+    back to every receiving copy. The dense round never forms
+    ``theta``: for each client-ownable layer and each of its leaves it
+    contracts the layer's rows of ``A`` with every owning group's
+    stacked leaf in that leaf's own layout (``[C, k_g] @ [k_g,
+    *leaf]``, summed over the groups — ``_contract_leaf``, the Pallas
+    ``clustered_agg`` kernel behind ``use_kernel``) and gathers each
+    group's rows of the ``[C, *leaf]`` aggregate along the client axis
+    (``_round``). Each client row is read once and written once; one
+    jitted program per net and round.
 
     Built once from a template of the client params; repeat rounds
     reuse the cached treedefs/shapes/offsets and the jitted aggregate
     functions (retraced only when the segment count changes).
 
-    ``mesh``: client-axis sharding for the round. The ``[K, D]``
-    buffer's rows shard over the mesh's ('pod', 'data') axes and the
-    reduction becomes a shard_map partial-sum + psum; falls back to
-    the single-device path when K is not divisible by the mesh (or
-    the mesh is trivial). Plans are cached per mesh identity — see
-    ``get_federation_plan``.
+    ``mesh``: client-axis sharding for the round. Each group's stacked
+    leaves shard their rows over the mesh's ('pod', 'data') axes (a
+    group the mesh does not divide stays whole on every shard) and the
+    contraction becomes a shard_map partial sum + psum of the
+    ``[C, ...]`` aggregates; falls back to the single-device path when
+    K is not divisible by the mesh (or the mesh is trivial). Plans are
+    cached per mesh identity — see ``get_federation_plan``.
 
     ``chunk_size``: enables ``aggregate_chunked`` — the round streams
-    each group's stacked rows in chunks of this many clients instead
-    of building the dense ``[K, D]`` buffer (O(chunk + clusters)
+    each group's stacked rows in chunks of this many clients through a
+    flat ``[c, n_cols]`` tile (one column run per ownable layer,
+    zero-filled where a cut does not own it; O(chunk + clusters)
     memory). ``cohort_size``: declared per-round participant count
     (part of the plan cache key so cohort and full-participation
     rounds never share a jitted program; the actual cohort arrives per
@@ -405,8 +439,9 @@ class FederationPlan:
             for g in groups}
         layers = sorted({l for ls in owned.values() for l in ls})
 
-        # columns: contiguous run per client-ownable layer; leaf specs
-        # must agree across groups (same layer definition).
+        # columns of the chunk stream's flat tile: contiguous run per
+        # client-ownable layer; leaf specs must agree across groups
+        # (same layer definition).
         self._col_runs: Dict[int, Tuple[int, int]] = {}
         col = 0
         layer_specs: Dict[int, Tuple] = {}
@@ -445,14 +480,18 @@ class FederationPlan:
                 sid += g.size
         self.n_copies = sid          # receiving (layer, client copy) pairs
 
-        # per-layer owner rows for the weight blocks
+        # per-layer owner rows for the weight blocks, and the owning
+        # groups' entries for the leafwise round (same layer order)
         self._layer_rows: List[Tuple[int, np.ndarray, np.ndarray]] = []
+        self._layer_entries: List[Tuple[int, List[_SegmentEntry]]] = []
         cids_arr = np.asarray(self.row_cids, np.int64)
         for l in layers:
             rows = np.concatenate([
                 np.arange(*self._group_rows[g.name]) for g in groups
                 if l in owned[g.name]])
             self._layer_rows.append((l, rows, cids_arr[rows]))
+            self._layer_entries.append(
+                (l, [e for e in self.entries if e.layer == l]))
         # static per-copy indices for the in-jit weight-matrix build
         # (device_weight_segments): seg_id(copy) = layer_pos * C + label
         layer_pos = {l: i for i, (l, _, _) in enumerate(self._layer_rows)}
@@ -551,11 +590,11 @@ class FederationPlan:
         C = int(num_clusters)
         n_seg = len(self._layer_rows) * C
         S = max(_SEGMENT_PAD, -(-n_seg // _SEGMENT_PAD) * _SEGMENT_PAD)
-        A = jnp.zeros((S, self.n_rows), jnp.float32)
         w = weights.astype(jnp.float32)
         part = (jnp.ones_like(w) if participation is None
                 else participation.astype(jnp.float32))
-        for li, (l, rows, cids) in enumerate(self._layer_rows):
+        blocks = []
+        for l, _, cids in self._layer_rows:
             lab = labels[cids]                                     # [R]
             onehot = jax.nn.one_hot(lab, C, dtype=jnp.float32)     # [R, C]
             raw = onehot * w[cids][:, None]
@@ -565,37 +604,215 @@ class FederationPlan:
             blk = jnp.where(denom > 0,
                             raw / jnp.where(denom > 0, denom, 1.0),
                             mem / jnp.maximum(cnt, 1.0))           # [R, C]
-            A = A.at[li * C:(li + 1) * C, rows].set(blk.T)
+            # the owner rows are whole groups in row order: place each
+            # group's columns by static slices (no scatter)
+            cols, off = [], 0
+            for gname in self._groups_order:
+                r0, r1 = self._group_rows[gname]
+                if l in self._owned[gname]:
+                    cols.append(blk.T[:, off:off + r1 - r0])
+                    off += r1 - r0
+                else:
+                    cols.append(jnp.zeros((C, r1 - r0), jnp.float32))
+            blocks.append(jnp.concatenate(cols, axis=1))
+        blocks.append(jnp.zeros((S - n_seg, self.n_rows), jnp.float32))
+        A = jnp.concatenate(blocks, axis=0)
         seg_ids = (jnp.asarray(self._copy_layer_pos[:self.n_copies]) * C
                    + labels[jnp.asarray(self._copy_cid[:self.n_copies])]
                    ).astype(jnp.int32)
         return A, seg_ids
 
-    # -- device-side flatten / unflatten (inside jit) ----------------------
-    def _flatten(self, net_params: Dict[str, Dict[str, Any]]) -> jnp.ndarray:
-        bufs = []
-        for gname in self._groups_order:
-            r0, r1 = self._group_rows[gname]
-            k = r1 - r0
-            parts, col = [], 0
-            for l, (c0, w) in sorted(self._col_runs.items()):
-                assert c0 == col
-                if l in self._owned[gname]:
-                    leaves = jax.tree_util.tree_leaves(net_params[gname][str(l)])
-                    parts.append(jnp.concatenate(
-                        [x.reshape(k, -1).astype(jnp.float32)
-                         for x in leaves], axis=1))
-                else:
-                    parts.append(jnp.zeros((k, w), jnp.float32))
-                col += w
-            bufs.append(jnp.concatenate(parts, axis=1))
-        return jnp.concatenate(bufs, axis=0)
+    # -- the leafwise round (inside jit) ------------------------------------
+    def _layer_aggregates(self, net_params: Dict[str, Dict[str, Any]],
+                          blocks: Dict[str, Dict[str, jnp.ndarray]],
+                          use_kernel: bool) -> Dict[str, List[jnp.ndarray]]:
+        """``{str(l): [agg [C, *leaf] for each leaf of layer l]}``: every
+        leaf of a client-ownable layer contracted over its owners'
+        stacked client axis with the layer's weight block, group by
+        group (``blocks[str(l)][gname]`` is that group's ``[C, k_g]``
+        columns)."""
+        aggs = {}
+        for l, owners in self._layer_entries:
+            leaves = [jax.tree_util.tree_leaves(net_params[e.gname][str(l)])
+                      for e in owners]
+            ws = [blocks[str(l)][e.gname] for e in owners]
+            aggs[str(l)] = [
+                _contract_leaf(ws, [lv[i] for lv in leaves], use_kernel)
+                for i in range(len(owners[0].leaves))]
+        return aggs
 
+    def _aggregates_fn(self, use_kernel: bool) -> Callable:
+        """(net_params, blocks) -> replicated ``_layer_aggregates``.
+
+        Sharded path: each group's stacked leaves and weight columns
+        split over the client axis; every shard contracts its local
+        rows (the Pallas kernel runs per shard) into partial ``[C, ...]``
+        aggregates, and one psum over the client axis leaves them
+        replicated for the broadcast gather (DESIGN.md §Sharded
+        federation). A group whose size the mesh does not divide stays
+        whole on every shard, and each shard keeps every n-th of its
+        weight columns, so the psum still counts each row once."""
+        if self._client_axes is None:
+            return lambda p, b: self._layer_aggregates(p, b, use_kernel)
+        axes = self._client_axes
+        names = (axes,) if isinstance(axes, str) else tuple(axes)
+        n = int(np.prod([self.mesh.shape[a] for a in names]))
+        split = {g: r1 > r0 and client_axes(self.mesh, r1 - r0) == axes
+                 for g, (r0, r1) in self._group_rows.items()}
+
+        def local(net_params, blocks):
+            me = jax.lax.axis_index(names)
+            blocks = {l: {g: w if split[g] else
+                          w * (jnp.arange(w.shape[1]) % n == me)
+                          for g, w in per.items()}
+                      for l, per in blocks.items()}
+            return jax.lax.psum(
+                self._layer_aggregates(net_params, blocks, use_kernel),
+                names)
+
+        def run(net_params, blocks):
+            p_specs = {g: jax.tree_util.tree_map(
+                lambda x, g=g: P(axes) if split[g] else P(), tree)
+                for g, tree in net_params.items()}
+            b_specs = {l: {g: P(None, axes) if split[g] else P()
+                           for g in per} for l, per in blocks.items()}
+            # check_vma=False: pallas_call has no shard_map replication
+            # rule; the out_spec is still fully replicated (psum).
+            return jax.shard_map(local, mesh=self.mesh,
+                                 in_specs=(p_specs, b_specs),
+                                 out_specs=P(), check_vma=False)(
+                                     net_params, blocks)
+        return run
+
+    def _round(self, aggregates: Callable,
+               net_params: Dict[str, Dict[str, Any]], A: jnp.ndarray,
+               seg_ids: jnp.ndarray, C: Optional[int],
+               recv: Optional[jnp.ndarray] = None
+               ) -> Dict[str, Dict[str, Any]]:
+        """The dense Eq.-16 round, leaf by leaf in the stored layout.
+
+        ``A [S, K]`` and ``seg_ids`` as ``(device_)weight_segments``
+        give them. ``C``: layer ``li``'s segments are rows
+        ``li*C:(li+1)*C`` of ``A`` (the device path); ``None``: every
+        layer contracts all of ``A``'s rows over its owner columns (the
+        host path — rows of other layers are zero there and never
+        gathered). Each group's new leaf is its segment rows of the
+        layer's aggregate, gathered along the client axis. ``recv``
+        ([n_copies] bool): cohort recv-select — copies whose client did
+        not participate keep their leaves."""
+        def seg0(li):
+            return 0 if C is None else li * C
+
+        blocks = {}
+        for li, (l, owners) in enumerate(self._layer_entries):
+            A_l = A if C is None else A[seg0(li):seg0(li) + C]
+            blocks[str(l)] = {e.gname: A_l[:, e.row0:e.row1]
+                              for e in owners}
+        aggs = aggregates(net_params, blocks)
+        out: Dict[str, Dict[str, Any]] = {}
+        for li, (l, owners) in enumerate(self._layer_entries):
+            for e in owners:
+                idx = seg_ids[e.sid0:e.sid1] - seg0(li)
+                olds = jax.tree_util.tree_leaves(net_params[e.gname][str(l)])
+                leaves = []
+                for agg, old, s in zip(aggs[str(l)], olds, e.leaves):
+                    leaf = jnp.take(agg, idx, axis=0,
+                                    mode="clip").astype(s.dtype)
+                    if recv is not None:
+                        m = recv[e.sid0:e.sid1].reshape(
+                            (e.row1 - e.row0,) + (1,) * len(s.shape))
+                        leaf = jnp.where(m, leaf, old)
+                    leaves.append(leaf)
+                out.setdefault(e.gname, {})[str(l)] = \
+                    jax.tree_util.tree_unflatten(e.treedef, leaves)
+        return out
+
+    def _make_agg_fn(self, use_kernel: bool, donate: bool,
+                     with_cohort: bool = False) -> Callable:
+        aggregates = self._aggregates_fn(use_kernel)
+        if with_cohort:
+            def fn(net_params, A, seg_ids, recv):
+                return self._round(aggregates, net_params, A, seg_ids,
+                                   None, recv)
+        else:
+            def fn(net_params, A, seg_ids):
+                return self._round(aggregates, net_params, A, seg_ids, None)
+        return _jit_round(fn, donate, "agg")
+
+    def aggregate(self, net_params: Dict[str, Dict[str, Any]],
+                  A: np.ndarray, seg_ids: np.ndarray,
+                  use_kernel: bool = False,
+                  donate: bool = False,
+                  cohort_mask: Optional[np.ndarray] = None
+                  ) -> Dict[str, Dict[str, Any]]:
+        key = (use_kernel, donate, cohort_mask is not None)
+        if key not in self._agg_fns:
+            self._agg_fns[key] = self._make_agg_fn(
+                use_kernel, donate, cohort_mask is not None)
+        args = [net_params, jnp.asarray(A, jnp.float32),
+                jnp.asarray(seg_ids, jnp.int32)]
+        if cohort_mask is not None:
+            recv = np.asarray(cohort_mask, bool)[
+                self._copy_cid[:self.n_copies]]
+            args.append(jnp.asarray(recv))
+        spans.count("agg.leafwise")
+        return self._agg_fns[key](*args)
+
+    def _make_agg_device_fn(self, num_clusters: int, use_kernel: bool,
+                            donate: bool, with_cohort: bool = False
+                            ) -> Callable:
+        aggregates = self._aggregates_fn(use_kernel)
+        C = int(num_clusters)
+        copy_cid = jnp.asarray(self._copy_cid[:self.n_copies])
+
+        if with_cohort:
+            def fn(net_params, weights, labels, cohort_mask):
+                A, seg_ids = self.device_weight_segments(
+                    weights, labels, C,
+                    participation=cohort_mask.astype(jnp.float32))
+                recv = cohort_mask.astype(bool)[copy_cid]
+                return self._round(aggregates, net_params, A, seg_ids, C,
+                                   recv)
+        else:
+            def fn(net_params, weights, labels):
+                A, seg_ids = self.device_weight_segments(weights, labels, C)
+                return self._round(aggregates, net_params, A, seg_ids, C)
+        return _jit_round(fn, donate, "agg")
+
+    def aggregate_device(self, net_params: Dict[str, Dict[str, Any]],
+                         weights: jnp.ndarray, labels: jnp.ndarray,
+                         num_clusters: int, use_kernel: bool = False,
+                         donate: bool = False,
+                         cohort_mask: Optional[jnp.ndarray] = None
+                         ) -> Dict[str, Dict[str, Any]]:
+        """Device-resident round: weights/labels are per-client device
+        arrays (label ids < the static ``num_clusters`` bound); the
+        Eq.-15/16 weight matrix is assembled in-jit — no host numpy
+        between the inputs and the aggregated params. weights/labels
+        are never donated (the caller reuses them across nets).
+        ``cohort_mask`` ([K] bool, weights pre-renormalized over the
+        cohort — ``kld.cohort_federation_weights_jax``): non-members
+        keep their original params via the recv-select."""
+        key = ("device", int(num_clusters), use_kernel, donate,
+               cohort_mask is not None)
+        if key not in self._agg_fns:
+            self._agg_fns[key] = self._make_agg_device_fn(
+                int(num_clusters), use_kernel, donate,
+                cohort_mask is not None)
+        spans.count("agg.leafwise")
+        if cohort_mask is not None:
+            return self._agg_fns[key](net_params, weights, labels,
+                                      cohort_mask)
+        return self._agg_fns[key](net_params, weights, labels)
+
+    # -- chunk-streamed round (DESIGN.md §Chunk-streamed aggregation) ------
     def _unflatten(self, agg: jnp.ndarray, seg_ids: jnp.ndarray,
                    originals: Optional[Dict[str, Dict[str, Any]]] = None,
                    recv: Optional[jnp.ndarray] = None
                    ) -> Dict[str, Dict[str, Any]]:
-        """``recv`` ([n_copies] bool, with ``originals`` = the
+        """The chunk stream's broadcast: gather each receiving copy's
+        segment row of the flat ``agg [S, n_cols]`` and cut it back into
+        leaves. ``recv`` ([n_copies] bool, with ``originals`` = the
         pre-round net_params): cohort recv-select — copies whose
         client did not participate this round keep their original
         leaves instead of gathering a segment aggregate they took no
@@ -624,129 +841,6 @@ class FederationPlan:
                 jax.tree_util.tree_unflatten(e.treedef, leaves)
         return out
 
-    # -- the jitted round --------------------------------------------------
-    def _reduce_fn(self, use_kernel: bool) -> Callable:
-        """(A [S, K], theta [K, D]) -> replicated agg [S, D] f32."""
-        if self._client_axes is None:
-            # single-device / fallback path: one full-K contraction.
-            return lambda A, theta: _reduce_rows(A, theta, use_kernel)
-
-        # Sharded path: theta rows and A columns split over the client
-        # axis; each shard contracts its local row block (the Pallas
-        # kernel runs per-shard) into a partial [S, D], and one psum
-        # over the client axis leaves the full cluster means replicated
-        # on every host — S*D is tiny next to K*D, and _unflatten's
-        # seg_ids gather needs every segment row locally, so a
-        # psum_scatter would only defer the same all-gather (DESIGN.md
-        # §Sharded federation).
-        axes = self._client_axes
-        axis_names = (axes,) if isinstance(axes, str) else tuple(axes)
-
-        def local_partial(a_blk, theta_blk):
-            part = _reduce_rows(a_blk, theta_blk, use_kernel)
-            return jax.lax.psum(part.astype(jnp.float32), axis_names)
-
-        # check_vma=False: pallas_call has no shard_map replication
-        # rule; the out_spec below is still fully replicated (psum).
-        return jax.shard_map(local_partial, mesh=self.mesh,
-                             in_specs=(P(None, axes), P(axes, None)),
-                             out_specs=P(None, None), check_vma=False)
-
-    def _make_agg_fn(self, use_kernel: bool, donate: bool,
-                     with_cohort: bool = False) -> Callable:
-        reduce = self._reduce_fn(use_kernel)
-        theta_sharding = (None if self._client_axes is None else
-                          NamedSharding(self.mesh, P(self._client_axes, None)))
-
-        def core(net_params, A, seg_ids):
-            theta = self._flatten(net_params)
-            if theta_sharding is not None:
-                theta = jax.lax.with_sharding_constraint(theta, theta_sharding)
-            return reduce(A, theta)
-
-        if with_cohort:
-            def fn(net_params, A, seg_ids, recv):
-                agg = core(net_params, A, seg_ids)
-                return self._unflatten(agg, seg_ids,
-                                       originals=net_params, recv=recv)
-        else:
-            def fn(net_params, A, seg_ids):
-                return self._unflatten(core(net_params, A, seg_ids), seg_ids)
-        return _jit_round(fn, donate, "agg")
-
-    def aggregate(self, net_params: Dict[str, Dict[str, Any]],
-                  A: np.ndarray, seg_ids: np.ndarray,
-                  use_kernel: bool = False,
-                  donate: bool = False,
-                  cohort_mask: Optional[np.ndarray] = None
-                  ) -> Dict[str, Dict[str, Any]]:
-        key = (use_kernel, donate, cohort_mask is not None)
-        if key not in self._agg_fns:
-            self._agg_fns[key] = self._make_agg_fn(
-                use_kernel, donate, cohort_mask is not None)
-        args = [net_params, jnp.asarray(A, jnp.float32),
-                jnp.asarray(seg_ids, jnp.int32)]
-        if cohort_mask is not None:
-            recv = np.asarray(cohort_mask, bool)[
-                self._copy_cid[:self.n_copies]]
-            args.append(jnp.asarray(recv))
-        return self._agg_fns[key](*args)
-
-    def _make_agg_device_fn(self, num_clusters: int, use_kernel: bool,
-                            donate: bool, with_cohort: bool = False
-                            ) -> Callable:
-        reduce = self._reduce_fn(use_kernel)
-        theta_sharding = (None if self._client_axes is None else
-                          NamedSharding(self.mesh, P(self._client_axes, None)))
-        copy_cid = jnp.asarray(self._copy_cid[:self.n_copies])
-
-        def core(net_params, weights, labels, participation=None):
-            A, seg_ids = self.device_weight_segments(
-                weights, labels, num_clusters, participation=participation)
-            theta = self._flatten(net_params)
-            if theta_sharding is not None:
-                theta = jax.lax.with_sharding_constraint(theta, theta_sharding)
-            return reduce(A, theta), seg_ids
-
-        if with_cohort:
-            def fn(net_params, weights, labels, cohort_mask):
-                agg, seg_ids = core(net_params, weights, labels,
-                                    cohort_mask.astype(jnp.float32))
-                recv = cohort_mask.astype(bool)[copy_cid]
-                return self._unflatten(agg, seg_ids,
-                                       originals=net_params, recv=recv)
-        else:
-            def fn(net_params, weights, labels):
-                agg, seg_ids = core(net_params, weights, labels)
-                return self._unflatten(agg, seg_ids)
-        return _jit_round(fn, donate, "agg")
-
-    def aggregate_device(self, net_params: Dict[str, Dict[str, Any]],
-                         weights: jnp.ndarray, labels: jnp.ndarray,
-                         num_clusters: int, use_kernel: bool = False,
-                         donate: bool = False,
-                         cohort_mask: Optional[jnp.ndarray] = None
-                         ) -> Dict[str, Dict[str, Any]]:
-        """Device-resident round: weights/labels are per-client device
-        arrays (label ids < the static ``num_clusters`` bound); the
-        Eq.-15/16 weight matrix is assembled in-jit — no host numpy
-        between the inputs and the aggregated params. weights/labels
-        are never donated (the caller reuses them across nets).
-        ``cohort_mask`` ([K] bool, weights pre-renormalized over the
-        cohort — ``kld.cohort_federation_weights_jax``): non-members
-        keep their original params via the recv-select."""
-        key = ("device", int(num_clusters), use_kernel, donate,
-               cohort_mask is not None)
-        if key not in self._agg_fns:
-            self._agg_fns[key] = self._make_agg_device_fn(
-                int(num_clusters), use_kernel, donate,
-                cohort_mask is not None)
-        if cohort_mask is not None:
-            return self._agg_fns[key](net_params, weights, labels,
-                                      cohort_mask)
-        return self._agg_fns[key](net_params, weights, labels)
-
-    # -- chunk-streamed round (DESIGN.md §Chunk-streamed aggregation) ------
     def _accumulate_chunks(self, net_params, cids_by_group, w_all, lab_all,
                            part_all, zero_seg, num_clusters: int, chunk: int,
                            use_kernel: bool):
@@ -982,6 +1076,7 @@ class FederationPlan:
         if self.chunk_size is None:
             raise ValueError("plan was built without chunk_size; pass "
                              "chunk_size= to get_federation_plan")
+        spans.count("agg.chunked")
         if self._chunk_axes is not None:
             key = ("chunked", int(num_clusters), use_kernel, donate,
                    cohort_mask is not None)
@@ -1028,10 +1123,11 @@ class FederationPlan:
 
     # -- memory envelopes --------------------------------------------------
     def dense_buffer_bytes(self) -> int:
-        """f32 bytes of the dense ``theta [K, D]`` flat client buffer
-        the non-chunked paths materialize (the O(clients) term the
-        chunk stream eliminates)."""
-        return 4 * self.n_rows * self.n_cols
+        """f32 bytes of the client rows a dense round reads: every owner
+        copy of every client-ownable layer, no zero columns. The round
+        writes as many anew (in place when donated), so this is its
+        O(clients) term, the one the chunk stream eliminates."""
+        return 4 * sum((e.row1 - e.row0) * e.width for e in self.entries)
 
     def chunked_buffer_bytes(self, num_clusters: int) -> int:
         """f32 bytes of the chunk stream's working set: one

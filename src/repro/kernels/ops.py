@@ -38,18 +38,24 @@ def weighted_agg(stacked: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
 
 
 @jax.jit
-def clustered_agg(weights: jnp.ndarray, stacked: jnp.ndarray) -> jnp.ndarray:
-    """Multi-output clustered aggregation: weights [S, K] rows are
-    normalized (layer, cluster) segments; out[s] = sum_k W[s,k] *
-    stacked[k, ...] in f32 (any trailing shape).
+def clustered_agg(weights, stacked) -> jnp.ndarray:
+    """Clustered aggregation of one stacked leaf, split over the groups
+    that own it: ``out[c] = sum_g sum_k weights[g][c, k] *
+    stacked[g][k, ...]`` in f32 (any trailing shape, shared by the
+    groups). ``weights``/``stacked`` are one array each or matching
+    sequences (one entry per group; the groups are never concatenated).
+    The kernel runs on each leaf's ``leaf_view``, a reshape that keeps
+    the two minor dims.
 
     NOTE: the clustered family takes weights FIRST (matmul order,
     ``W @ theta``), unlike the legacy ``weighted_agg(stacked, w)`` —
     a transposed call fails on shape unless S == K."""
-    K = stacked.shape[0]
-    flat = stacked.reshape(K, -1)
-    out = _wa.clustered_agg_flat(weights, flat, interpret=_interpret())
-    return out.reshape((weights.shape[0],) + stacked.shape[1:])
+    if not isinstance(stacked, (list, tuple)):
+        weights, stacked = [weights], [stacked]
+    tail = stacked[0].shape[1:]
+    views = [x.reshape(_wa.leaf_view(x.shape)) for x in stacked]
+    out = _wa.clustered_agg(weights, views, interpret=_interpret())
+    return out.reshape((out.shape[0],) + tail)
 
 
 @jax.jit

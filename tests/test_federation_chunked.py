@@ -1,11 +1,11 @@
 """Chunk-streamed clustered aggregation vs the dense fused path and
 the legacy numpy-loop oracle (DESIGN.md §Chunk-streamed aggregation).
 
-The chunked path never materializes the dense ``theta [K, D]`` buffer:
-a ``lax.scan`` over fixed-size client chunks accumulates per-segment
-weighted partial sums and weight masses, and one normalize at the end
-divides them out. Summation is therefore *re-associated* relative to
-the dense single-matmul round, so equivalence is tolerance-bounded
+The chunked path never holds every client row at once, as the dense
+round does: a ``lax.scan`` over fixed-size client chunks accumulates
+per-segment weighted partial sums and weight masses, and one normalize
+at the end divides them out. Summation is therefore *re-associated*
+relative to the dense round, so equivalence is tolerance-bounded
 (f32 accumulator, observed max-abs ~1e-7 on GAN-sized layers), not
 bit-exact — except where a case is engineered to take the identical
 compute path, which is asserted byte-identical.
@@ -149,8 +149,9 @@ def test_chunked_requires_chunked_plan(population):
 
 
 def test_buffer_bytes_are_population_independent(population):
-    """The acceptance claim in O() form: the dense buffer grows with
-    the client count, the chunk working set doesn't."""
+    """The acceptance claim in O() form: the dense round's client rows
+    (read once, written once) grow with the client count, the chunk
+    working set doesn't."""
     groups, params, _, _ = population
     tmpl = {g.name: params[g.name]["G"] for g in groups}
     plan = get_federation_plan(groups, "G", 5, tmpl, chunk_size=2)

@@ -1,5 +1,7 @@
 """Fused single-dispatch federation round vs the legacy quadruple-loop
 oracle: equivalence over heterogeneous cuts and >=3 clusters, the
+paper population's seven groups (host weights, device weights, FedAvg
+and cohort rounds, with and without the Pallas kernel), the
 zero-weight-sum fallback, fedavg as the degenerate single-cluster
 case, and plan caching."""
 import jax
@@ -9,6 +11,7 @@ import pytest
 
 from repro.core import federation as fed
 from repro.core.federation import (FederationPlan, federate_client_params,
+                                   federate_client_params_device,
                                    fedavg_uniform, get_federation_plan)
 from repro.core.latency import Cut, PAPER_DEVICES
 from repro.core.splitting import (client_owned_layers, group_by_profile,
@@ -19,11 +22,15 @@ N_LAYERS = {"G": 5, "D": 5}
 # heterogeneous cuts -> 4 profile groups with distinct owned-layer sets
 HET_CUTS = (Cut(1, 3, 1, 3), Cut(2, 4, 2, 4), Cut(1, 4, 2, 3),
             Cut(2, 3, 1, 4))
+# the benchmark population's cuts (bench/configs/cgan28-paper100.json):
+# device1 owns no G layer 1 or 3 and no D layer 1 or 3, device2 no D
+# layer 1
+PAPER_CUTS = (Cut(1, 4, 1, 4), Cut(2, 3, 1, 3)) + (Cut(2, 3, 2, 3),) * 5
 
 
-def build_population(n_clients, n_profiles, seed=0):
+def build_population(n_clients, n_profiles, seed=0, cuts_cycle=HET_CUTS):
     devices = [PAPER_DEVICES[i % n_profiles] for i in range(n_clients)]
-    cuts = [HET_CUTS[i % n_profiles] for i in range(n_clients)]
+    cuts = [cuts_cycle[i % n_profiles] for i in range(n_clients)]
     groups = group_by_profile(devices, cuts)
     key = jax.random.PRNGKey(seed)
     params = {}
@@ -36,6 +43,27 @@ def build_population(n_clients, n_profiles, seed=0):
                     lambda kk, l=l: defs[l][0](kk, jnp.float32))(
                         jax.random.split(sub, g.size))
     return groups, params
+
+
+def paper_template_shapes(net, n_clients=100, sharding=None):
+    """(groups, template) of the benchmark population at its real size,
+    as shapes only: every group's stacked client leaves of ``net`` as
+    ``jax.ShapeDtypeStruct`` (for lowering and compiling a round
+    without allocating it)."""
+    devices = [PAPER_DEVICES[i % 7] for i in range(n_clients)]
+    groups = group_by_profile(devices, [PAPER_CUTS[i % 7]
+                                        for i in range(n_clients)])
+    defs = GEN_LAYER_DEFS if net == "G" else DISC_LAYER_DEFS
+    template = {}
+    for g in groups:
+        template[g.name] = {}
+        for l in client_owned_layers(layer_pair(g.cut, net), 5):
+            tree = jax.eval_shape(lambda k, l=l: defs[l][0](k, jnp.float32),
+                                  jax.random.PRNGKey(0))
+            template[g.name][str(l)] = jax.tree_util.tree_map(
+                lambda x, n=g.size: jax.ShapeDtypeStruct(
+                    (n,) + x.shape, x.dtype, sharding=sharding), tree)
+    return groups, template
 
 
 def assert_trees_close(got, want, atol=1e-5):
@@ -133,12 +161,20 @@ def test_plan_cache_reuse_and_layout(population):
     assert len(p1.entries) == n_entries
     assert p1.n_copies == sum(g.size * len(client_owned_layers(
         layer_pair(g.cut, "G"), 5)) for g in groups)
-    # flat width = union of ownable layer widths, layer runs disjoint
-    runs = sorted(p1._col_runs.values())
-    assert runs[0][0] == 0
-    for (c0, w), (c1, _) in zip(runs, runs[1:]):
-        assert c0 + w == c1
-    assert p1.n_cols == runs[-1][0] + runs[-1][1]
+    # leafwise layout: each ownable layer lists exactly its owner
+    # groups, whose rows partition the layer's owner rows, and the
+    # round reads only owned rows (no zero columns)
+    for (l, rows, _), (l2, owners) in zip(p1._layer_rows,
+                                          p1._layer_entries):
+        assert l == l2 and owners
+        assert {e.gname for e in owners} == {
+            g.name for g in groups
+            if l in client_owned_layers(layer_pair(g.cut, "G"), 5)}
+        assert np.array_equal(np.concatenate(
+            [np.arange(e.row0, e.row1) for e in owners]), rows)
+    assert p1.dense_buffer_bytes() == 4 * sum(
+        x.size for g in groups for x in jax.tree_util.tree_leaves(
+            params[g.name]["G"]))
 
 
 def test_weight_segments_block_structure(population):
@@ -156,3 +192,84 @@ def test_weight_segments_block_structure(population):
     np.testing.assert_allclose(A[:n_real].sum(1), 1.0, atol=1e-6)
     assert np.all(A[n_real:] == 0)
     assert A.shape[1] == plan.n_rows and len(seg_ids) == plan.n_copies
+
+
+# --------------------------------------------------------------------------
+# the paper population's seven groups: leafwise round vs the legacy loop
+# --------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def paper_population():
+    """Two clients on each of the seven paper device profiles, with the
+    benchmark's cuts and the real GAN layer shapes."""
+    groups, params = build_population(14, 7, seed=5, cuts_cycle=PAPER_CUTS)
+    assert len(groups) == 7
+    rng = np.random.default_rng(8)
+    return groups, params, rng.random(14), np.arange(14) % 3
+
+
+def _legacy(groups, params, weights, labels):
+    return federate_client_params(groups, params, weights, labels,
+                                  n_layers=N_LAYERS, fused=False)
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_paper_groups_host_weights_round(paper_population, use_kernel):
+    groups, params, weights, labels = paper_population
+    got = federate_client_params(groups, params, weights, labels,
+                                 n_layers=N_LAYERS, use_kernel=use_kernel)
+    assert_trees_close(got, _legacy(groups, params, weights, labels))
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_paper_groups_device_weights_round(paper_population, use_kernel):
+    """The in-jit weight matrix at the static cluster bound (more
+    clusters than are used), the path the trainer's rounds take."""
+    groups, params, weights, labels = paper_population
+    w = weights.copy()
+    for c in np.unique(labels):
+        w[labels == c] /= w[labels == c].sum()
+    got = federate_client_params_device(
+        groups, params, jnp.asarray(w, jnp.float32),
+        jnp.asarray(labels, jnp.int32), num_clusters=6, n_layers=N_LAYERS,
+        use_kernel=use_kernel)
+    assert_trees_close(got, _legacy(groups, params, weights, labels))
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_paper_groups_fedavg_round(paper_population, use_kernel):
+    groups, params, _, _ = paper_population
+    sizes = np.random.default_rng(9).integers(10, 100, 14)
+    want = fedavg_uniform(groups, params, sizes, n_layers=N_LAYERS,
+                          fused=False)
+    got = fedavg_uniform(groups, params, sizes, n_layers=N_LAYERS,
+                         use_kernel=use_kernel)
+    assert_trees_close(got, want)
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+def test_paper_groups_cohort_round(paper_population, use_kernel):
+    """A sampled cohort: participants get the legacy loop's aggregate
+    over the cohort's weights, the others keep their params bit for
+    bit."""
+    groups, params, weights, labels = paper_population
+    mask = np.zeros(14, bool)
+    mask[[0, 2, 3, 6, 7, 9, 12]] = True
+    w = np.where(mask, weights, 0.0)
+    got = federate_client_params(groups, params, w, labels,
+                                 n_layers=N_LAYERS, use_kernel=use_kernel,
+                                 cohort_mask=mask)
+    want = _legacy(groups, params, w, labels)
+    for g in groups:
+        keep = ~mask[np.asarray(g.client_ids)]
+        for net in N_LAYERS:
+            for l, tree in params[g.name][net].items():
+                for old, new, ref in zip(
+                        jax.tree_util.tree_leaves(tree),
+                        jax.tree_util.tree_leaves(got[g.name][net][l]),
+                        jax.tree_util.tree_leaves(want[g.name][net][l])):
+                    old, new = np.asarray(old), np.asarray(new)
+                    assert np.array_equal(new[keep], old[keep])
+                    np.testing.assert_allclose(new[~keep],
+                                               np.asarray(ref)[~keep],
+                                               atol=1e-5)

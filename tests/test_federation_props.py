@@ -1,7 +1,8 @@
-"""Property tests (hypothesis) for ``FederationPlan``: the
-flatten -> unflatten roundtrip is the identity for random pytrees and
-cuts, and ``weight_segments`` rows are normalized within each
-(layer, cluster) block — including the zero-weight-sum fallback.
+"""Property tests (hypothesis) for ``FederationPlan``: the identity
+round (every client its own cluster) returns random pytrees under
+random cuts unchanged, and ``weight_segments`` rows are normalized
+within each (layer, cluster) block — including the zero-weight-sum
+fallback.
 
 Cases are derived deterministically from hypothesis-drawn integers
 (seed + structure knobs) so each example is reproducible from its
@@ -59,27 +60,29 @@ def _random_case(seed: int, n_layers: int, n_groups: int):
        n_groups=st.integers(1, 3))
 @settings(max_examples=15, deadline=None)
 def test_flatten_unflatten_roundtrip_is_identity(seed, n_layers, n_groups):
-    """With the identity weight matrix (every copy its own segment,
-    seg_ids = own row), _unflatten(_flatten(params)) == params exactly:
-    the flat layout loses nothing and the zero-filled non-owned runs
-    are never read back."""
+    """The identity round: with every client in a cluster of its own at
+    weight 1, the leafwise round (host and device weight paths) returns
+    every client's params bit for bit — each leaf is contracted and
+    gathered back in its own layout, and no other group's or layer's
+    rows leak in."""
     groups, template = _random_case(seed, n_layers, n_groups)
     assume(any(template[g.name] for g in groups))   # someone owns a layer
     plan = FederationPlan(groups, "G", n_layers, template)
-    theta = plan._flatten(template)
-    assert theta.shape == (plan.n_rows, plan.n_cols)
-    seg_ids = np.zeros(plan.n_copies, np.int32)
-    for e in plan.entries:
-        seg_ids[e.sid0:e.sid1] = np.arange(e.row0, e.row1)
-    out = plan._unflatten(theta, jnp.asarray(seg_ids))
-    for g in groups:
-        for l, tree in template[g.name].items():
-            got = jax.tree_util.tree_leaves(out[g.name][l])
-            want = jax.tree_util.tree_leaves(tree)
-            assert len(got) == len(want)
-            for a, b in zip(got, want):
-                assert a.dtype == b.dtype and a.shape == b.shape
-                assert np.array_equal(np.asarray(a), b)
+    K = plan.n_rows
+    labels = np.arange(K)
+    A, seg_ids = plan.weight_segments(np.ones(K), labels)
+    host = plan.aggregate(template, A, seg_ids)
+    device = plan.aggregate_device(template, jnp.ones(K, jnp.float32),
+                                   jnp.asarray(labels, jnp.int32), K)
+    for out in (host, device):
+        for g in groups:
+            for l, tree in template[g.name].items():
+                got = jax.tree_util.tree_leaves(out[g.name][l])
+                want = jax.tree_util.tree_leaves(tree)
+                assert len(got) == len(want)
+                for a, b in zip(got, want):
+                    assert a.dtype == b.dtype and a.shape == b.shape
+                    assert np.array_equal(np.asarray(a), b)
 
 
 @given(seed=st.integers(0, 2**31 - 1), n_layers=st.integers(2, 4),

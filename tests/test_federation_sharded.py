@@ -12,7 +12,10 @@ meshes of 2/4/8 devices out of the forced 8 via
 Matrix: heterogeneous cuts (4 profile groups), >= 3 clusters, client
 counts both divisible (16) and non-divisible (10) by the mesh — the
 latter exercising ``sharding.policy.client_axes``'s sanitize fallback
-to the unsharded path — plus plan-cache keying on (mesh identity,
+to the unsharded path — the paper population's seven groups (group
+sizes 3 and 2: some split over the mesh, some kept whole on every
+shard) on the host-weights, device-weights, FedAvg and cohort rounds,
+plus plan-cache keying on (mesh identity,
 chunk_size, cohort_size) and the ``mesh=None`` default staying
 byte-identical. The chunk-streamed round's own sharded matrix lives
 in tests/test_federation_chunked.py.
@@ -189,9 +192,70 @@ def _check_trainer_sharded_rounds():
                                 tr_none.state[net]["client"], atol=1e-6)
 
 
+def _check_paper_groups():
+    """The paper's seven groups (16 clients: groups of 3 and 2, real GAN
+    shapes, the benchmark's cuts) on 1/2/4/8-device meshes, with and
+    without the Pallas kernel, against the legacy loop, for the
+    host-weights, device-weights, FedAvg and cohort rounds: groups the
+    mesh divides split over it, the rest stay whole on every shard.
+    (Both kernel settings on every mesh size are left out for time;
+    each runs on two mesh sizes.)"""
+    import jax
+    import jax.numpy as jnp
+    from repro.core.federation import (federate_client_params,
+                                       federate_client_params_device,
+                                       fedavg_uniform, get_federation_plan)
+    from repro.launch.mesh import make_federation_mesh
+    from test_federation_fused import N_LAYERS, PAPER_CUTS, build_population
+    assert jax.device_count() >= 8
+    groups, params = build_population(16, 7, seed=2, cuts_cycle=PAPER_CUTS)
+    assert sorted(g.size for g in groups) == [2] * 5 + [3] * 2
+    rng = np.random.default_rng(4)
+    weights, labels = rng.random(16), np.arange(16) % N_CLUSTERS
+    mask = rng.random(16) < 0.6
+    sizes = rng.integers(10, 100, 16)
+    cohort_w = np.where(mask, weights, 0.0)
+    device_w = weights / np.bincount(labels, weights)[labels]
+    rounds = {
+        "host": lambda **kw: federate_client_params(
+            groups, params, weights, labels, n_layers=N_LAYERS, **kw),
+        "device": lambda **kw: federate_client_params_device(
+            groups, params, jnp.asarray(device_w, jnp.float32),
+            jnp.asarray(labels, jnp.int32), num_clusters=6,
+            n_layers=N_LAYERS, **kw),
+        "fedavg": lambda **kw: fedavg_uniform(
+            groups, params, sizes, n_layers=N_LAYERS, **kw),
+        # the unsharded cohort round is checked against the legacy loop
+        # in test_federation_fused.py
+        "cohort": lambda **kw: federate_client_params(
+            groups, params, cohort_w, labels, n_layers=N_LAYERS,
+            cohort_mask=mask, **kw)}
+    legacy = federate_client_params(groups, params, weights, labels,
+                                    n_layers=N_LAYERS, fused=False)
+    wants = {"host": legacy, "device": legacy,
+             "fedavg": fedavg_uniform(groups, params, sizes,
+                                      n_layers=N_LAYERS, fused=False),
+             "cohort": rounds["cohort"]()}
+    tmpl = {g.name: params[g.name]["G"] for g in groups}
+    # the kernel alternates with the mesh size: the 2-device mesh splits
+    # the groups of 2 and keeps the groups of 3 whole, the 4- and
+    # 8-device meshes keep every group whole
+    for nd, use_kernel in ((1, False), (2, True), (4, False), (8, True)):
+        mesh = make_federation_mesh(nd)
+        plan = get_federation_plan(groups, "G", 5, tmpl, mesh=mesh)
+        assert (plan._client_axes is None) == (nd == 1)
+        for path, run in rounds.items():
+            _assert_trees_equal(run(mesh=mesh, use_kernel=use_kernel),
+                                wants[path], atol=1e-5)
+
+
 # --------------------------------------------------------------------------
 # pytest wrappers
 # --------------------------------------------------------------------------
+
+def test_sharded_paper_groups(multihost):
+    multihost(MODULE, "_check_paper_groups")
+
 
 def test_sharded_equivalence_matrix(multihost):
     multihost(MODULE, "_check_equivalence_matrix")

@@ -1,12 +1,15 @@
 """Per-kernel correctness: shape/dtype sweeps, interpret-mode Pallas vs
 the pure-jnp oracles in repro.kernels.ref."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.weighted_agg import clustered_agg_flat, weighted_agg_flat
+from repro.kernels.weighted_agg import (clustered_agg, kernel_fits, leaf_view,
+                                        weighted_agg_flat)
 from repro.kernels.kmeans_assign import kmeans_assign
 from repro.kernels.flash_decode import flash_decode
 from repro.kernels.mem_attention import mem_attention
@@ -40,11 +43,12 @@ def test_weighted_agg_nd_tree():
 @pytest.mark.parametrize("D", [128, 8192, 10_001])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_clustered_agg_sweep(S, K, D, dtype):
+    """Rank-1 leaves (view ``[K, D]``), one owner group."""
     key = jax.random.PRNGKey(S * 100 + K * 10 + D)
     x = jax.random.normal(key, (K, D), dtype)
     w = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(2), (S, K)),
                        axis=1)
-    got = clustered_agg_flat(w, x, interpret=True)
+    got = clustered_agg([w], [x], interpret=True)
     want = ref.clustered_agg_ref(w, x)
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -54,13 +58,18 @@ def test_clustered_agg_sweep(S, K, D, dtype):
 
 @pytest.mark.parametrize("block_tiles", [1, 2, None])
 def test_clustered_agg_block_tiles(block_tiles):
-    """Tiled streaming (compiled-mode layout) and coalesced interpret
-    blocks agree with the oracle."""
-    S, K, D = 6, 5, 3 * 8 * 1024 + 77
-    x = jax.random.normal(jax.random.PRNGKey(0), (K, D))
-    w = jax.random.normal(jax.random.PRNGKey(1), (S, K))
-    got = clustered_agg_flat(w, x, block_tiles=block_tiles, interpret=True)
-    want = ref.clustered_agg_ref(w, x)
+    """A streamed grid of (8 * block_tiles, 128) blocks, ragged at both
+    edges (compiled-mode tiling), and the whole-view interpret block
+    agree with the oracle, over two owner groups."""
+    C, M, L = 6, 3 * 8 + 5, 2 * 128 + 77
+    xs = [jax.random.normal(jax.random.PRNGKey(r), (r, M, L))
+          for r in (5, 3)]
+    ws = [jax.random.normal(jax.random.PRNGKey(10 + r), (C, r))
+          for r in (5, 3)]
+    block = None if block_tiles is None else (8 * block_tiles, 128)
+    got = clustered_agg(ws, xs, block=block, interpret=True)
+    want = (ref.clustered_agg_ref(ws[0], xs[0])
+            + ref.clustered_agg_ref(ws[1], xs[1]))
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-5, rtol=1e-5)
 
@@ -78,9 +87,109 @@ def test_weighted_agg_is_single_segment_case():
     x = jax.random.normal(jax.random.PRNGKey(0), (5, 1000))
     w = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(1), (5,)))
     single = weighted_agg_flat(x, w, interpret=True)
-    multi = clustered_agg_flat(w.reshape(1, -1), x, interpret=True)[0]
+    multi = clustered_agg([w.reshape(1, -1)], [x], interpret=True)[0]
     np.testing.assert_allclose(np.asarray(single), np.asarray(multi),
                                atol=1e-6)
+
+
+# owner counts split into profile groups the way the paper population
+# splits them (100 = 7 groups, 85 = 6, one group below that)
+_OWNER_GROUPS = {1: (1,), 14: (14,), 15: (15,),
+                 85: (15, 14, 14, 14, 14, 14),
+                 100: (15, 15, 14, 14, 14, 14, 14)}
+_MIDDLE = {2: (), 3: (16,), 4: (3, 8), 5: (2, 3, 8)}
+
+
+def _leaf_case(rank, minor, owners, C, seed):
+    shape = _MIDDLE[rank] + (minor,)
+    key = jax.random.PRNGKey(seed)
+    xs, ws = [], []
+    for r in _OWNER_GROUPS[owners]:
+        kx, kw, key = jax.random.split(key, 3)
+        xs.append(jax.random.normal(kx, (r,) + shape, jnp.float32))
+        ws.append(jax.random.uniform(kw, (C, r), jnp.float32))
+    return ws, xs
+
+
+def _leaf_ref(ws, xs):
+    return sum(jnp.einsum("cr,r...->c...", w, x,
+                          precision=jax.lax.Precision.HIGHEST)
+               for w, x in zip(ws, xs))
+
+
+@pytest.mark.parametrize("owners", sorted(_OWNER_GROUPS))
+@pytest.mark.parametrize("minor", [1, 64, 128, 256])
+@pytest.mark.parametrize("rank", [2, 3, 4, 5])
+def test_clustered_agg_leaf_views(rank, minor, owners):
+    """Stacked leaves of rank 2-5 with minor dims 1/64/128/256, owned by
+    1-100 clients over up to 7 groups, through ``ops.clustered_agg``'s
+    leaf views (C cycles over 1-8 across the grid)."""
+    C = 1 + (rank * 7 + minor + owners) % 8
+    ws, xs = _leaf_case(rank, minor, owners, C, seed=rank * 1000 + minor)
+    got = ops.clustered_agg(ws, xs)
+    assert got.shape == (C,) + xs[0].shape[1:]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(_leaf_ref(ws, xs)),
+                               atol=2e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("C", list(range(1, 9)))
+def test_clustered_agg_cluster_counts(C):
+    """Every cluster count 1-8 on a paper-shaped view (85 owners in six
+    groups, ``[k, 4, 4, 8, 128]``), streamed in compiled-size blocks."""
+    key = jax.random.PRNGKey(C)
+    ws, xs = [], []
+    for r in _OWNER_GROUPS[85]:
+        kx, kw, key = jax.random.split(key, 3)
+        xs.append(jax.random.normal(kx, (r, 4, 4, 8, 128), jnp.float32))
+        ws.append(jax.random.uniform(kw, (C, r), jnp.float32))
+    views = [x.reshape(leaf_view(x.shape)) for x in xs]
+    got = clustered_agg(ws, views, block=(64, 128), interpret=True)
+    want = _leaf_ref(ws, views)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=1e-5)
+
+
+def test_kernel_fits_by_leaf_shape():
+    """The compiled kernel takes whole lane tiles in a bitcast view; the
+    paper GAN's small and ragged leaves stay in XLA."""
+    assert kernel_fits((110, 12544))          # G layer 0 dense weight
+    assert kernel_fits((12544,))              # its bias
+    assert kernel_fits((4, 4, 256, 128))      # G layer 1 conv weight
+    assert kernel_fits((4, 4, 128, 256))      # D layer 3 conv weight
+    assert not kernel_fits((256,))            # BatchNorm vectors
+    assert not kernel_fits((3, 3, 64, 1))     # G layer 4 (576 weights)
+    assert not kernel_fits((4096, 1))         # D layer 4
+    assert not kernel_fits((4, 4, 128, 64))   # half a lane tile
+    assert not kernel_fits((4, 4, 2, 128))    # view not a bitcast
+    assert leaf_view((7, 4, 4, 256, 128)) == (7, 4096, 128)
+    assert leaf_view((7, 12544)) == (7, 12544)
+    assert leaf_view((7,)) == (7, 1)
+
+
+def _f32_array_sizes(text):
+    """Element counts of every f32 array type in a lowered program."""
+    return [int(np.prod([int(d) for d in dims.split("x")]))
+            for dims in re.findall(r"tensor<((?:\d+x)+)f32>", text)
+            for dims in [dims.rstrip("x")]]
+
+
+@pytest.mark.parametrize("use_kernel", [False, True])
+@pytest.mark.parametrize("net", ["G", "D"])
+def test_paper100_round_holds_no_flat_buffer(net, use_kernel):
+    """The aggregation program for the benchmark's 100 clients in seven
+    groups holds no array larger than twice the largest single stacked
+    leaf of one group (the flat ``[K, D]`` buffer was 10x that)."""
+    from repro.core.federation import FederationPlan
+    from test_federation_fused import paper_template_shapes
+    groups, template = paper_template_shapes(net)
+    plan = FederationPlan(groups, net, 5, template)
+    fn = plan._make_agg_device_fn(6, use_kernel, donate=False)
+    text = fn.lower(template, jax.ShapeDtypeStruct((100,), jnp.float32),
+                    jax.ShapeDtypeStruct((100,), jnp.int32)).as_text()
+    largest = max(x.size for x in jax.tree_util.tree_leaves(template))
+    sizes = _f32_array_sizes(text)
+    assert largest in sizes
+    assert max(sizes) <= 2 * largest
 
 
 @pytest.mark.parametrize("N", [1, 100, 257])

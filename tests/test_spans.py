@@ -150,10 +150,28 @@ def test_counters_count_one_build_per_miss(traced):
     first = _delta(traced["first"], traced["before"])
     assert first == {"programs_built.epoch": 1, "programs_built.agg": 4,
                      "programs_built.cluster": 1, "rounds.fedavg": 1,
-                     "rounds.clustered": 1}
+                     "rounds.clustered": 1, "agg.leafwise": 4}
     # the traced epoch and clustered round build nothing
     assert _delta(traced["repeat"], traced["first"]) == {
-        "rounds.clustered": 1}
+        "rounds.clustered": 1, "agg.leafwise": 2}
+
+
+@pytest.mark.parametrize("chunk_size", [None, 2])
+def test_aggregation_path_counters(chunk_size):
+    """A dense round counts ``agg.leafwise`` once per net (G and D) and a
+    chunk-streamed round ``agg.chunked`` instead."""
+    import numpy as np
+    from repro.core.federation import federate_client_params
+    from test_federation_fused import N_LAYERS, build_population
+    groups, params = build_population(4, 2)
+    before = spans.counters()
+    federate_client_params(groups, params, np.ones(4), np.arange(4) % 2,
+                           n_layers=N_LAYERS, chunk_size=chunk_size)
+    got = _delta(spans.counters(), before)
+    got.pop("programs_built.agg", None)
+    got.pop("programs_built.agg_chunked", None)
+    assert got == ({"agg.leafwise": 2} if chunk_size is None
+                   else {"agg.chunked": 2})
 
 
 def test_counters_are_copies():

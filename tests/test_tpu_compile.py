@@ -24,6 +24,9 @@ from repro.launch.serve_split import SplitLMConfig
 
 F32, I32 = jnp.float32, jnp.int32
 LM = SplitLMConfig()
+# the benchmark population's seven group sizes (100 clients round-robin
+# over the paper's seven device profiles)
+PAPER_OWNERS = (15, 15, 14, 14, 14, 14, 14)
 
 
 @pytest.fixture(scope="module")
@@ -52,11 +55,22 @@ def one_chip():
 
 # name -> (kernel called with interpret=False, argument shapes/dtypes)
 CASES = {
-    # Eq.-16 round at the paper population: 100 client copies, the
-    # padded segment rows, a 2^20-wide flat parameter run
+    # Eq.-16 round at the paper population, one leaf in its stored
+    # layout: G layer 1's conv weight over its six owner groups, G
+    # layer 0's dense weight (a ragged 110-row view) and its bias over
+    # all seven, six cluster rows each
     "clustered_agg_paper": (
-        lambda w, x: wa.clustered_agg_flat(w, x, interpret=False),
-        [((15, 100), F32), ((100, 2 ** 20), F32)]),
+        lambda *a: wa.clustered_agg(a[:6], a[6:], interpret=False),
+        [((6, r), F32) for r in PAPER_OWNERS[1:]]
+        + [((r, 4096, 128), F32) for r in PAPER_OWNERS[1:]]),
+    "clustered_agg_paper_dense": (
+        lambda *a: wa.clustered_agg(a[:7], a[7:], interpret=False),
+        [((6, r), F32) for r in PAPER_OWNERS]
+        + [((r, 110, 12544), F32) for r in PAPER_OWNERS]),
+    "clustered_agg_paper_bias": (
+        lambda *a: wa.clustered_agg(a[:7], a[7:], interpret=False),
+        [((6, r), F32) for r in PAPER_OWNERS]
+        + [((r, 12544), F32) for r in PAPER_OWNERS]),
     "weighted_agg_paper": (
         lambda x, w: wa.weighted_agg_flat(x, w, interpret=False),
         [((100, 2 ** 20), F32), ((100,), F32)]),
@@ -96,3 +110,34 @@ def test_kernel_compiles_for_v5e(one_chip, name):
             for shape, dtype in specs]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("net", ["G", "D"])
+def test_paper100_round_compiles_for_v5e(one_chip, net, monkeypatch):
+    """The device-weights aggregation program at the benchmark's 100
+    clients compiles with the Pallas kernel for its lane-dense leaves,
+    the leaves stay in their layout (no array beyond twice the largest
+    group's stacked leaf), and the outputs alias the donated inputs."""
+    import re
+
+    import numpy as np
+    from repro.core.federation import FederationPlan
+    from repro.kernels import ops
+    from test_federation_fused import paper_template_shapes
+    # the kernel wrappers pick interpret mode from the process's backend
+    # (the CPU here); compile them as the chip would
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    groups, template = paper_template_shapes(net, sharding=one_chip)
+    plan = FederationPlan(groups, net, 5, template)
+    fn = plan._make_agg_device_fn(6, True, donate=True)
+    compiled = fn.lower(
+        template, jax.ShapeDtypeStruct((100,), F32, sharding=one_chip),
+        jax.ShapeDtypeStruct((100,), I32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    largest = max(x.size for x in jax.tree_util.tree_leaves(template))
+    sizes = [int(np.prod([int(d) for d in dims.split(",")]))
+             for dims in re.findall(r"f32\[([0-9,]+)\]", text)]
+    assert max(sizes) <= 2 * largest
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= mem.output_size_in_bytes - 2 ** 20
