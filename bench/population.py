@@ -8,9 +8,18 @@ program's data code cannot change the yardstick.
 ``repro.data.build_scenario`` at a small size.
 
 The population is a pure function of the traffic's ``scenario``,
-``num_clients``, ``base_size`` and ``partition_seed``; ``--seed`` never
-changes it. Built once per checkout and cached under ``bench/.cache/``
-(git-ignored), keyed by those settings.
+``num_clients``, ``base_size`` and ``partition_seed``, and of the net's
+image shape (H, W, C) and class count (``IMAGE``, ``NUM_CLASSES`` of
+``bench/models/<model>.py``); ``--seed`` never changes it. Built once
+per checkout and cached under ``bench/.cache/`` (git-ignored), keyed by
+those settings.
+
+``repro.data`` renders 28x28x1 images of 10 classes only, and at that
+shape and class count the copy equals it. At any other shape or class
+count the generator is the benchmark's own: each family is drawn on an
+H x W grid over [-1, 1], the same grey image on every channel, each
+channel with noise of its own; gratings turn by ``pi / classes`` a
+class, and blobs take ``class % 5 + 1`` spots on one of two rings.
 """
 from __future__ import annotations
 
@@ -22,20 +31,22 @@ from typing import List, Sequence, Tuple
 
 import numpy as np
 
-NUM_CLASSES = 10
-IMG = 28
 CACHE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), ".cache")
+# the shape and class count the cache was first keyed at, without them
+# in the key: kept so that no checkout rebuilds its cached population
+UNKEYED = ((28, 28, 1), 10)
 
 
-def _grid():
-    ax = np.linspace(-1.0, 1.0, IMG, dtype=np.float32)
-    return np.meshgrid(ax, ax, indexing="ij")
+def _grid(hw):
+    return np.meshgrid(np.linspace(-1.0, 1.0, hw[0], dtype=np.float32),
+                       np.linspace(-1.0, 1.0, hw[1], dtype=np.float32),
+                       indexing="ij")
 
 
-def _gratings(cls, rng):
-    yy, xx = _grid()
+def _gratings(cls, rng, hw, num_classes):
+    yy, xx = _grid(hw)
     n = cls.shape[0]
-    theta = cls * (np.pi / NUM_CLASSES) + rng.normal(0, 0.05, n)
+    theta = cls * (np.pi / num_classes) + rng.normal(0, 0.05, n)
     freq = 4.0 + (cls % 3) + rng.normal(0, 0.1, n)
     phase = rng.uniform(0, 2 * np.pi, n)
     t = theta[:, None, None]
@@ -43,13 +54,13 @@ def _gratings(cls, rng):
     return np.sin(freq[:, None, None] * np.pi * proj + phase[:, None, None])
 
 
-def _blobs(cls, rng):
-    yy, xx = _grid()
+def _blobs(cls, rng, hw, num_classes):
+    yy, xx = _grid(hw)
     n = cls.shape[0]
-    img = np.full((n, IMG, IMG), -1.0, np.float32)
+    img = np.full((n,) + tuple(hw), -1.0, np.float32)
     for i in range(n):
         k = int(cls[i]) % 5 + 1
-        r = 0.25 + 0.5 * ((int(cls[i]) // 5) + 1) / 3.0
+        r = 0.25 + 0.5 * ((int(cls[i]) // 5) % 2 + 1) / 3.0
         ang0 = rng.uniform(0, 2 * np.pi)
         for j in range(k):
             a = ang0 + 2 * np.pi * j / k
@@ -64,16 +75,20 @@ def _blobs(cls, rng):
 _FAMILIES = {"gratings": _gratings, "blobs": _blobs}
 
 
-def _make_dataset(domain: str, n: int, seed: int, noise: float = 0.12):
+def _make_dataset(domain: str, n: int, seed: int, image, num_classes: int,
+                  noise: float = 0.12):
     rng = np.random.default_rng(
         seed + zlib.crc32(domain.encode()) % (2 ** 16))
-    labels = rng.integers(0, NUM_CLASSES, n).astype(np.int32)
-    imgs = _FAMILIES[domain](labels, rng).astype(np.float32)
+    labels = rng.integers(0, num_classes, n).astype(np.int32)
+    imgs = _FAMILIES[domain](labels, rng, image[:2], num_classes
+                             ).astype(np.float32)
+    imgs = np.repeat(imgs[..., None], image[2], -1)
     imgs = imgs + rng.normal(0, noise, imgs.shape).astype(np.float32)
-    return np.clip(imgs, -1.0, 1.0)[..., None], labels
+    return np.clip(imgs, -1.0, 1.0), labels
 
 
-def _exclusion_plan(num_clients: int, plan, seed: int) -> List[List[int]]:
+def _exclusion_plan(num_clients: int, plan, seed: int, num_classes: int
+                    ) -> List[List[int]]:
     rng = np.random.default_rng(seed)
     exclusions: List[List[int]] = [[] for _ in range(num_clients)]
     order = rng.permutation(num_clients)
@@ -83,16 +98,18 @@ def _exclusion_plan(num_clients: int, plan, seed: int) -> List[List[int]]:
             if idx >= num_clients:
                 break
             exclusions[order[idx]] = list(
-                rng.choice(NUM_CLASSES, n_excl, replace=False))
+                rng.choice(num_classes, n_excl, replace=False))
             idx += 1
     return exclusions
 
 
-def _domain_clients(domain, client_ids, sizes, exclusions, seed):
+def _domain_clients(domain, client_ids, sizes, exclusions, seed, image,
+                    num_classes):
     out = []
     for i, cid in enumerate(client_ids):
         raw_n = int(sizes[i] * (1.0 + 0.25 * len(exclusions[i]) + 0.2)) + 8
-        imgs, labs = _make_dataset(domain, raw_n, seed * 10007 + cid)
+        imgs, labs = _make_dataset(domain, raw_n, seed * 10007 + cid, image,
+                                   num_classes)
         if exclusions[i]:
             keep = ~np.isin(labs, np.asarray(exclusions[i], labs.dtype))
             imgs, labs = imgs[keep], labs[keep]
@@ -100,7 +117,8 @@ def _domain_clients(domain, client_ids, sizes, exclusions, seed):
     return out
 
 
-def two_domain_noniid(num_clients: int, base_size: int, seed: int
+def two_domain_noniid(num_clients: int, base_size: int, seed: int,
+                      image: Tuple[int, int, int], num_classes: int
                       ) -> List[Tuple[str, np.ndarray, np.ndarray]]:
     """``2dom_noniid``: half the clients on gratings, half on blobs;
     per domain 20/5/5 (per 100) clients lose 2/3/4 labels; sizes drawn
@@ -116,24 +134,34 @@ def two_domain_noniid(num_clients: int, base_size: int, seed: int
         ids = list(range(g * half, g * half + half))
         plan = [(scale(20), 2), (scale(5), 3), (scale(5), 4)]
         pool = [base_size, int(base_size * 2 / 3)]
-        excl = _exclusion_plan(half, plan, seed + g)
+        excl = _exclusion_plan(half, plan, seed + g, num_classes)
         sizes = [int(rng.choice(pool)) for _ in range(half)]
-        out += _domain_clients(dom, ids, sizes, excl, seed + g)
+        out += _domain_clients(dom, ids, sizes, excl, seed + g, image,
+                               num_classes)
     return out
 
 
 SCENARIOS = {"2dom_noniid": two_domain_noniid}
 
 
-def population(traffic: dict, num_clients: int
-               ) -> List[Tuple[str, np.ndarray, np.ndarray]]:
-    """The traffic's population of ``num_clients`` clients, from the
-    cache or built and cached."""
+def cache_path(traffic: dict, num_clients: int, image, num_classes: int
+               ) -> str:
     key = {k: traffic[k] for k in ("scenario", "base_size",
                                    "partition_seed")}
     key["num_clients"] = int(num_clients)
+    if (tuple(image), int(num_classes)) != UNKEYED:
+        key.update(image=[int(x) for x in image],
+                   num_classes=int(num_classes))
     tag = hashlib.sha1(json.dumps(key, sort_keys=True).encode()).hexdigest()
-    path = os.path.join(CACHE_DIR, f"population-{tag[:16]}.npz")
+    return os.path.join(CACHE_DIR, f"population-{tag[:16]}.npz")
+
+
+def population(traffic: dict, num_clients: int, image, num_classes: int
+               ) -> List[Tuple[str, np.ndarray, np.ndarray]]:
+    """The traffic's population of ``num_clients`` clients with images
+    of shape ``image`` (H, W, C) and ``num_classes`` labels, from the
+    cache or built and cached."""
+    path = cache_path(traffic, num_clients, image, num_classes)
     if os.path.exists(path):
         with np.load(path) as z:
             domains = [str(d) for d in z["domains"]]
@@ -143,9 +171,9 @@ def population(traffic: dict, num_clients: int
             images, labels = z["images"], z["labels"]
         return [(d, images[s:e], labels[s:e])
                 for d, s, e in zip(domains, starts, ends)]
-    clients = SCENARIOS[key["scenario"]](key["num_clients"],
-                                         key["base_size"],
-                                         key["partition_seed"])
+    clients = SCENARIOS[traffic["scenario"]](
+        int(num_clients), traffic["base_size"], traffic["partition_seed"],
+        tuple(image), int(num_classes))
     os.makedirs(CACHE_DIR, exist_ok=True)
     tmp = path + ".tmp.npz"
     np.savez(tmp, domains=np.array([c[0] for c in clients]),

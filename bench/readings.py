@@ -34,13 +34,13 @@ if sys.path and os.path.abspath(sys.path[0]) == _HERE:
     sys.path.pop(0)
 sys.path[:0] = [os.path.dirname(_HERE), os.path.join(os.path.dirname(_HERE), "src")]
 
-from bench import compare, population, reference, run, spec  # noqa: E402
+from bench import compare, reference, run, spec  # noqa: E402
 
 
 def _setting(cell: dict, seed: int):
     cfg = spec.read_json(spec.config_path(cell["config"]))
     traffic = spec.read_json(spec.traffic_path(cell["traffic"]))
-    clients = population.population(traffic, cfg["num_clients"])
+    clients = run.clients_of(spec.load_model(cfg["model"]), cfg, traffic)
     groups = reference.groups_of(cfg)
     seed31 = seed % run.SEED_MOD
     return (cfg, clients, groups, seed31,

@@ -1,17 +1,17 @@
-"""Plain jax.numpy reference of what the timed path computes.
+"""Plain jax.numpy reference of what the timed path computes, for any
+split G/D net.
 
-Imports nothing of the program. It follows the published semantics:
+Imports nothing of the program. A net's own layers, weights and
+training step are in its module, ``bench/models/<model>.py``; they run
+on what this module gives, which follows the published semantics of
+arXiv:2507.12979:
 
-* the cGAN of arXiv:2507.12979 Table 3, layer by layer;
 * the U-shaped split: layer ``l`` of client ``c`` runs on the client's
   own weights where its cut owns ``l`` (head ``l < h``, tail ``l >= t``),
   and otherwise on the server's single copy, applied once to the
   concatenation of every client that delegates ``l``, so that the
   server's BatchNorm statistics span all of them;
-* one training step: a discriminator update (G forward without
-  gradient, D on real and on fake, non-saturating BCE), then a
-  generator update against the updated D; Adam; BatchNorm running
-  statistics from the real-data pass for D and from G's own pass;
+* Adam, and BatchNorm running statistics put back into the parameters;
 * batches drawn on the device from the training key, as the trainer
   states it: per step ``key, sub = split(key)``, per profile group ``i``
   ``split(fold_in(sub, i), 3)`` for the rows, ``z`` and the fake labels;
@@ -22,15 +22,14 @@ Imports nothing of the program. It follows the published semantics:
   within the cluster); Eq. 16: every owner of a layer receives the
   weighted mean of the owners of that layer in its cluster.
 
-``dtype`` and ``precision`` select the control: ``bfloat16`` compute for
-the step, ``Precision.HIGH`` (three bfloat16 passes) for the round.
+``Numerics`` and ``precision`` select the control: ``bfloat16`` compute
+for the step, ``Precision.HIGH`` (three bfloat16 passes) for the round.
 XLA:CPU computes float32 products exactly at any precision, so the CPU
 tests pass ``BF16_3X``, the three passes written out (``_matmul``).
 """
 from __future__ import annotations
 
 import dataclasses
-import zlib
 from typing import Dict, List, Sequence, Tuple
 
 import jax
@@ -40,13 +39,9 @@ from jax import lax
 
 HIGHEST = lax.Precision.HIGHEST
 BF16_3X = "bf16_3x"
-BN_EPS = 1e-5
-BN_MOMENTUM = 0.9
 ADAM_B2 = 0.999
 ADAM_EPS = 1e-8
-EMA_FEATURES = 7 * 7 * 128          # D's middle layer output, flattened
 BLOB_SPACING = 100.0                # planted blobs' offset step (planted_activations)
-N_LAYERS = 5
 
 
 # ---------------------------------------------------------------------------
@@ -85,187 +80,11 @@ def groups_of(cfg: dict) -> List[Group]:
                                           key=lambda kv: str(kv[0]))]
 
 
-# ---------------------------------------------------------------------------
-# parameters
-# ---------------------------------------------------------------------------
-
-def _bn_shapes(ch):
-    return {"scale": (ch,), "bias": (ch,), "mean": (ch,), "var": (ch,)}
-
-
-def layer_shapes(cfg: dict) -> Dict[str, List[dict]]:
-    """Leaf shapes of every layer of G and D at the configuration's
-    widths, in the trainer's parameter layout."""
-    g, d = cfg["generator"], cfg["discriminator"]
-    z, ncls = g["z_dim"], g["num_classes"]
-    fh, fw, fc = g["fc_out"]
-    G = [{"embed": {"table": (ncls, ncls)},
-          "fc": {"w": (z + ncls, fh * fw * fc), "b": (fh * fw * fc,)},
-          "bn": _bn_shapes(fc)}]
-    for i, (cin, cout, k, _) in enumerate(g["convt"]):
-        layer = {"convt": {"w": (k, k, cin, cout), "b": (cout,)}}
-        if i < len(g["convt"]) - 1:
-            layer["bn"] = _bn_shapes(cout)
-        G.append(layer)
-    ih, iw, _ = d["image"]
-    D = []
-    for i, (cin, cout, k, _) in enumerate(d["conv"]):
-        layer = {"conv": {"w": (k, k, cin, cout), "b": (cout,)},
-                 "bn": _bn_shapes(cout)}
-        if i == 0:
-            layer = {"embed": {"table": (d["num_classes"], ih * iw)}, **layer}
-        D.append(layer)
-    D.append({"fc": {"w": (d["fc_in"], 1), "b": (1,)}})
-    return {"G": G, "D": D}
-
-
-def _init_leaf(key, name: str, parent: str, shape, lead=()):
-    full = tuple(lead) + tuple(shape)
-    if parent == "bn":
-        return (jnp.ones(full, jnp.float32) if name in ("scale", "var")
-                else jnp.zeros(full, jnp.float32))
-    if name == "b":
-        return jnp.zeros(full, jnp.float32)
-    if name == "table":
-        return jax.random.normal(key, full, jnp.float32) / np.sqrt(shape[-1])
-    if parent == "fc":                      # glorot uniform
-        lim = np.sqrt(6.0 / (shape[0] + shape[1]))
-        return jax.random.uniform(key, full, jnp.float32, -lim, lim)
-    fan_in = int(np.prod(shape[:-1]))       # conv / convT, HWIO: he normal
-    return jax.random.normal(key, full, jnp.float32) * np.sqrt(2.0 / fan_in)
-
-
-def param_template(cfg: dict, groups: Sequence[Group]) -> dict:
-    """{net: {"client": {group: {layer: tree}}, "server": {layer: tree}}}
-    with leading client axes, as (lead, shape) pairs."""
-    shapes = layer_shapes(cfg)
-    out = {}
-    for net in ("G", "D"):
-        client = {g.name: {str(l): (g.size, shapes[net][l])
-                           for l in range(N_LAYERS) if g.owns(net, l)}
-                  for g in groups}
-        server = {str(l): ((), shapes[net][l]) for l in range(N_LAYERS)
-                  if any(not g.owns(net, l) for g in groups)}
-        out[net] = {"client": client, "server": server}
-    return out
-
-
-def init_params(cfg: dict, groups: Sequence[Group], seed: int):
-    """The benchmark's weights for ``seed``, made on the device in one
-    jitted call. Every leaf draws from ``fold_in(seed key, crc32(path))``."""
-    template = param_template(cfg, groups)
-
-    def build(key):
-        def walk(node, path):
-            if isinstance(node, tuple):
-                lead, shapes = node
-                lead = (lead,) if isinstance(lead, int) else lead
-                return {part: {name: _init_leaf(
-                    jax.random.fold_in(key, zlib.crc32(
-                        f"{path}/{part}/{name}".encode()) % (2 ** 31)),
-                    name, part, shp, lead)
-                    for name, shp in leaves.items()}
-                    for part, leaves in shapes.items()}
-            return {k: walk(v, f"{path}/{k}") for k, v in node.items()}
-        return walk(template, "")
-
-    return jax.jit(build)(jax.random.PRNGKey(seed))
-
-
-# ---------------------------------------------------------------------------
-# layers
-# ---------------------------------------------------------------------------
-
 @dataclasses.dataclass(frozen=True)
 class Numerics:
+    """The precision a net's reference layers compute in."""
     dtype: object = jnp.float32
     precision: object = HIGHEST
-
-
-def _bn(p, x, nx: Numerics):
-    axes = tuple(range(x.ndim - 1))
-    mean = jnp.mean(x, axes)
-    var = jnp.mean(jnp.square(x - mean), axes)
-    y = (x - mean) * lax.rsqrt(var + BN_EPS) * p["scale"].astype(nx.dtype) \
-        + p["bias"].astype(nx.dtype)
-    stats = {"mean": BN_MOMENTUM * p["mean"]
-             + (1 - BN_MOMENTUM) * mean.astype(jnp.float32),
-             "var": BN_MOMENTUM * p["var"]
-             + (1 - BN_MOMENTUM) * var.astype(jnp.float32)}
-    return y, stats
-
-
-def _dense(p, x, nx):
-    return jnp.dot(x, p["w"].astype(nx.dtype),
-                   precision=nx.precision) + p["b"].astype(nx.dtype)
-
-
-_DN = ("NHWC", "HWIO", "NHWC")
-
-
-def _conv(p, x, stride, nx):
-    y = lax.conv_general_dilated(x, p["w"].astype(nx.dtype), (stride, stride),
-                                 "SAME", dimension_numbers=_DN,
-                                 precision=nx.precision)
-    return y + p["b"].astype(nx.dtype)
-
-
-def _convt(p, x, stride, nx):
-    y = lax.conv_transpose(x, p["w"].astype(nx.dtype), (stride, stride),
-                           "SAME", dimension_numbers=_DN,
-                           precision=nx.precision)
-    return y + p["b"].astype(nx.dtype)
-
-
-def _lrelu(x):
-    return jnp.where(x >= 0, x, 0.2 * x)
-
-
-def make_layers(cfg: dict, nx: Numerics):
-    """[(apply(p, x) -> (y, bn_stats or None))] for G and for D."""
-    g, d = cfg["generator"], cfg["discriminator"]
-    fh, fw, fc = g["fc_out"]
-    ih, iw, _ = d["image"]
-
-    def g0(p, x):
-        z, y = x
-        h = jnp.concatenate([z.astype(nx.dtype),
-                             p["embed"]["table"].astype(nx.dtype)[y]], -1)
-        h = _dense(p["fc"], h, nx).reshape(-1, fh, fw, fc)
-        h, st = _bn(p["bn"], h, nx)
-        return jax.nn.relu(h), st
-
-    def gconvt(stride, last):
-        def apply(p, x):
-            h = _convt(p["convt"], x, stride, nx)
-            if last:
-                return jnp.tanh(h), None
-            h, st = _bn(p["bn"], h, nx)
-            return jax.nn.relu(h), st
-        return apply
-
-    def d0(p, x):
-        img, y = x
-        e = p["embed"]["table"].astype(nx.dtype)[y].reshape(-1, ih, iw, 1)
-        h = jnp.concatenate([img.astype(nx.dtype), e], -1)
-        h = _conv(p["conv"], h, d["conv"][0][3], nx)
-        h, st = _bn(p["bn"], h, nx)
-        return _lrelu(h), st
-
-    def dconv(stride):
-        def apply(p, x):
-            h, st = _bn(p["bn"], _conv(p["conv"], x, stride, nx), nx)
-            return _lrelu(h), st
-        return apply
-
-    def d4(p, x):
-        return _dense(p["fc"], x.reshape(x.shape[0], -1), nx)[:, 0], None
-
-    n_t = len(g["convt"])
-    G = [g0] + [gconvt(s, i == n_t - 1) for i, (_, _, _, s)
-                in enumerate(g["convt"])]
-    D = [d0] + [dconv(s) for (_, _, _, s) in d["conv"][1:]] + [d4]
-    return {"G": G, "D": D}
 
 
 # ---------------------------------------------------------------------------
@@ -313,16 +132,7 @@ def split_forward(layers, net: str, groups: Sequence[Group], params,
     return x, stats
 
 
-def _bce(logits, target):
-    l = logits.astype(jnp.float32)
-    return jnp.mean(jnp.maximum(l, 0) - l * target + jnp.log1p(jnp.exp(-jnp.abs(l))))
-
-
-def _all(out: Dict[str, jnp.ndarray]):
-    return jnp.concatenate([v.reshape(-1) for v in out.values()])
-
-
-def _with_stats(params, stats):
+def with_stats(params, stats):
     """Parameters with their BatchNorm running statistics replaced."""
     def put(node, st):
         node = dict(node)
@@ -335,7 +145,7 @@ def _with_stats(params, stats):
 
 
 # ---------------------------------------------------------------------------
-# Adam and the step
+# Adam, batches and the epoch
 # ---------------------------------------------------------------------------
 
 def adam_init(params):
@@ -378,52 +188,11 @@ def sample(data, key, groups: Sequence[Group], batch: int, z_dim: int,
     return out
 
 
-def make_step(cfg: dict, groups: Sequence[Group], nx: Numerics,
-              keep: int = None):
-    layers = make_layers(cfg, nx)
-    lr, b1, batch = cfg["lr"], cfg["adam_b1"], cfg["batch"]
-    z_dim = cfg["generator"]["z_dim"]
-    n_cls = cfg["generator"]["num_classes"]
-
-    def step(state, data, key):
-        key, sub = jax.random.split(key)
-        bt = sample(data, sub, groups, batch, z_dim, n_cls, keep)
-        gp, dp = state["G"], state["D"]
-
-        def d_loss(dp):
-            fake, _ = split_forward(layers["G"], "G", groups, gp, bt["fake"])
-            fake_in = {g.name: (lax.stop_gradient(fake[g.name]),
-                                bt["fake"][g.name][1]) for g in groups}
-            real, st = split_forward(layers["D"], "D", groups, dp, bt["real"])
-            lf, _ = split_forward(layers["D"], "D", groups, dp, fake_in)
-            return _bce(_all(real), 1.0) + _bce(_all(lf), 0.0), st
-
-        (loss_d, st_d), gd = jax.value_and_grad(d_loss, has_aux=True)(dp)
-        opt_d, dp_new = adam_update(state["opt_d"], gd, dp, lr, b1)
-        dp_new = _with_stats(dp_new, st_d)
-
-        def g_loss(gp):
-            fake, st = split_forward(layers["G"], "G", groups, gp, bt["fake"])
-            fake_in = {g.name: (fake[g.name], bt["fake"][g.name][1])
-                       for g in groups}
-            lg, _ = split_forward(layers["D"], "D", groups, dp_new, fake_in)
-            return _bce(_all(lg), 1.0), st
-
-        (loss_g, st_g), gg = jax.value_and_grad(g_loss, has_aux=True)(gp)
-        opt_g, gp_new = adam_update(state["opt_g"], gg, gp, lr, b1)
-        gp_new = _with_stats(gp_new, st_g)
-        new = {"G": gp_new, "D": dp_new, "opt_g": opt_g, "opt_d": opt_d}
-        return new, key, jnp.stack([loss_d, loss_g])
-
-    return jax.jit(step, donate_argnums=(0,))
-
-
-def train(cfg, groups, params, data, key, n_steps: int, nx: Numerics,
-          keep: int = None):
-    """``n_steps`` steps from ``params``. Returns (state, losses [n, 2])."""
+def train(step, params, data, key, n_steps: int):
+    """``n_steps`` of a net's ``step`` (its module's ``make_step``) from
+    ``params``. Returns (state, losses [n, 2])."""
     state = {"G": params["G"], "D": params["D"],
              "opt_g": adam_init(params["G"]), "opt_d": adam_init(params["D"])}
-    step = make_step(cfg, groups, nx, keep)
     losses = []
     for _ in range(n_steps):
         state, key, loss = step(state, data, key)
@@ -634,8 +403,10 @@ def out_of_span(groups: Sequence[Group], client_params, labels,
     return worst
 
 
-def planted_activations(num_clients: int, seed: int) -> np.ndarray:
-    """The benchmark's middle-activation EMA for the checked round: two
+def planted_activations(num_clients: int, features: int, seed: int
+                        ) -> np.ndarray:
+    """The benchmark's middle-activation EMA [num_clients, features] for
+    the checked round (``features``: the net's ``ema_features``): two
     to four well separated blobs (their count and members drawn from
     the seed), so that every correct stage 3 finds the same partition.
 
@@ -649,8 +420,8 @@ def planted_activations(num_clients: int, seed: int) -> np.ndarray:
     rng = np.random.default_rng([seed, 7])
     n_blobs = 2 + seed % 3
     members = rng.permutation(np.arange(num_clients) % n_blobs)
-    centers = rng.normal(0.0, 1.0, (n_blobs, EMA_FEATURES)) * 3.0
-    noise = rng.normal(0.0, 0.3, (num_clients, EMA_FEATURES))
+    centers = rng.normal(0.0, 1.0, (n_blobs, features)) * 3.0
+    noise = rng.normal(0.0, 0.3, (num_clients, features))
     offset = rng.permutation(n_blobs) * BLOB_SPACING
     return (centers[members] + noise
             + offset[members][:, None]).astype(np.float32)

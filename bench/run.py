@@ -1,10 +1,12 @@
 #!/usr/bin/env python3
-"""HuSCF-GAN training benchmark: one run of one cell on the chip.
+"""Split-GAN training benchmark: one run of one cell on the chip.
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Set-up builds the cell's population (cached under ``bench/.cache``),
-constructs ``HuSCFTrainer`` with the configuration's fixed cuts, swaps
+Everything specific to the configuration's net comes from its module,
+``bench/models/<model>.py`` (``spec.load_model``). Set-up builds the
+cell's population at the net's image shape (cached under
+``bench/.cache``), builds the trainer through the net's module, swaps
 in the benchmark's weights for ``--seed``, and warms every program the
 window runs: one epoch through ``train_steps``, the FedAvg warm-up
 rounds and one clustered ``federate()``. The window then loops
@@ -14,9 +16,10 @@ passed. ``--trace 1`` runs the same window under the profiler and
 reports the per-layer metrics instead of the end-to-end ones.
 
 After the window: the peak device memory is read, the trainer is
-freed, and the reference (``bench/reference.py``) recomputes the
-set-up's first epoch and clustered round from the same seed; the
-numbers compared are printed beside their limits (``bench/compare.py``).
+freed, and the reference (the net's layers and step over
+``bench/reference.py``) recomputes the set-up's first epoch and
+clustered round from the same seed; the numbers compared are printed
+beside their limits (``bench/compare.py``).
 
 Without a TPU, or on a chip whose kind has no peaks in
 ``bench/trace_reduce.py``, the run exits non-zero and prints no result.
@@ -42,7 +45,7 @@ sys.path[:0] = [_ROOT, os.path.join(_ROOT, "src")]
 
 import numpy as np  # noqa: E402
 
-from bench import adapter, compare, flops, population, reference  # noqa: E402
+from bench import adapter, compare, population, reference  # noqa: E402
 from bench import spec  # noqa: E402
 from bench import trace_reduce  # noqa: E402
 
@@ -121,23 +124,10 @@ def check_device(jax, chips: int) -> dict:
     return info
 
 
-def build_trainer(cfg, traffic, clients, seed31):
-    from repro.core.huscf import HuSCFConfig, HuSCFTrainer
-    from repro.core.latency import PAPER_DEVICES, Cut
-    from repro.data.partition import ClientSpec
-    by_name = {d.name: d for d in PAPER_DEVICES}
-    names = reference.device_list(cfg)
-    config = HuSCFConfig(batch=cfg["batch"],
-                         federate_every=traffic["federate_every"],
-                         beta=cfg["beta"], lr=cfg["lr"],
-                         adam_b1=cfg["adam_b1"], seed=seed31,
-                         use_kernel=cfg["use_kernel"],
-                         warmup_fed_rounds=cfg["warmup_fed_rounds"])
-    specs = [ClientSpec(i, dom, imgs, labs)
-             for i, (dom, imgs, labs) in enumerate(clients)]
-    return HuSCFTrainer(specs, [by_name[n] for n in names],
-                        cuts=[Cut(*cfg["cuts"][n]) for n in names],
-                        config=config)
+def clients_of(model, cfg, traffic):
+    """The traffic's population at the net's image shape and classes."""
+    return population.population(traffic, cfg["num_clients"], model.IMAGE,
+                                 model.NUM_CLASSES)
 
 
 def steps_per_epoch(clients, batch: int) -> int:
@@ -211,10 +201,11 @@ def run(args, require_chip: bool = True, cell=None, cfg=None,
     def phase(name):
         phases.append((name, time.perf_counter() - T0))
 
-    clients = population.population(traffic, cfg["num_clients"])
+    model = spec.load_model(cfg["model"])
+    clients = clients_of(model, cfg, traffic)
     phase("population")
     groups = reference.groups_of(cfg)
-    tr = build_trainer(cfg, traffic, clients, seed31)
+    tr = model.build_trainer(cfg, traffic, clients, seed31)
     phase("trainer")
     if [(g.name, tuple(g.client_ids)) for g in tr.groups] != \
             [(g.name, g.client_ids) for g in groups]:
@@ -226,7 +217,7 @@ def run(args, require_chip: bool = True, cell=None, cfg=None,
     layout = adapter.layout(tr)
     adapter.drop_weights(tr)
     gc.collect()
-    w0 = reference.init_params(cfg, groups, seed31)
+    w0 = model.init_params(cfg, groups, seed31)
     for net in ("G", "D"):
         if _structure(jax, w0[net]) != layout[net]:
             raise RuntimeError(f"net {net}: the trainer's parameter layout "
@@ -240,7 +231,7 @@ def run(args, require_chip: bool = True, cell=None, cfg=None,
     metrics = adapter.first_epoch(tr, n_steps)
     phase("epoch")
     t = time.perf_counter()
-    start = reference.init_params(cfg, groups, seed31)
+    start = model.init_params(cfg, groups, seed31)
     now = adapter.trained(tr)
     delta, first, rms = compare.leaf_norms(
         now["params"], {net: start[net] for net in ("G", "D")},
@@ -260,8 +251,9 @@ def run(args, require_chip: bool = True, cell=None, cfg=None,
     # the checked clustered round, on the benchmark's activations and
     # weights; it compiles the cluster chain and the clustered round
     t = time.perf_counter()
-    acts = reference.planted_activations(cfg["num_clients"], seed31)
-    adapter.plant_round(tr, acts, reference.init_params(cfg, groups, seed31))
+    acts = reference.planted_activations(
+        cfg["num_clients"], model.ema_features(cfg), seed31)
+    adapter.plant_round(tr, acts, model.init_params(cfg, groups, seed31))
     excluded += time.perf_counter() - t
     diag = tr.federate()
     jax.block_until_ready(tr.state)
@@ -339,7 +331,7 @@ def run(args, require_chip: bool = True, cell=None, cfg=None,
     # what the per-layer readers read besides the trace
     data = {"device": device, "steps": epochs * n_steps, "samples": samples,
             "rounds": rounds, "agg_layers": layers,
-            "flops_per_sample": flops.step_flops_per_sample(cfg)}
+            "flops_per_sample": model.step_flops_per_sample(cfg)}
     adapter.free(tr)
     del tr
     gc.collect()
@@ -398,15 +390,16 @@ def run(args, require_chip: bool = True, cell=None, cfg=None,
 
 def reference_train(jax, cfg, groups, clients, seed31, n_steps, nx=None,
                     keep=None):
-    """The reference's epoch: its readings, as the program's."""
-    nx = nx or reference.Numerics()
-    params = reference.init_params(cfg, groups, seed31)
+    """The reference's epoch: its readings, as the program's. ``keep``
+    as in ``reference.sample``."""
+    model = spec.load_model(cfg["model"])
+    step = model.make_step(cfg, groups, nx or reference.Numerics(), keep)
+    params = model.init_params(cfg, groups, seed31)
     data = reference.stage_data(clients, groups)
-    state, losses = reference.train(cfg, groups, params, data,
-                                    jax.random.PRNGKey(seed31 + 1), n_steps,
-                                    nx, keep)
+    state, losses = reference.train(step, params, data,
+                                    jax.random.PRNGKey(seed31 + 1), n_steps)
     del params
-    start = reference.init_params(cfg, groups, seed31)
+    start = model.init_params(cfg, groups, seed31)
     delta, first, rms = compare.leaf_norms(
         {net: state[net] for net in ("G", "D")},
         {net: start[net] for net in ("G", "D")},
@@ -421,13 +414,15 @@ def reference_round(jax, cfg, groups, clients, seed31, rows,
     """The reference's checked round. ``precision`` below HIGHEST gives
     the control; ``fedavg`` replaces the Eq.-15 weights by dataset
     shares, a fault."""
-    acts = reference.planted_activations(cfg["num_clients"], seed31)
+    model = spec.load_model(cfg["model"])
+    acts = reference.planted_activations(
+        cfg["num_clients"], model.ema_features(cfg), seed31)
     labels, k = reference.cluster(acts)
     sizes = population.sizes(clients)
     weights = (reference.fedavg_weights(sizes, labels) if fedavg else
                reference.federation_weights(acts, sizes, labels, cfg["beta"],
                                             precision))
-    params = reference.init_params(cfg, groups, seed31)
+    params = model.init_params(cfg, groups, seed31)
     client = {net: params[net]["client"] for net in ("G", "D")}
     agg = reference.aggregate_rows(groups, client, weights, labels, rows,
                                    precision)

@@ -3,9 +3,10 @@
 ``BENCHMARK.json`` names every cell, configuration and metric. Each
 configuration is ``bench/configs/<name>.json``, each traffic mix
 ``bench/traffic/<name>.json`` and each per-layer metric a reader
-``bench/metrics/<name>.py``; the harness loads them by those names, so
-a cell, a configuration or a metric is added by adding files and
-entries, never by editing code.
+``bench/metrics/<name>.py``; a net is ``bench/models/<model>.py``, named
+by its configuration's ``"model"`` key. The harness loads them by those
+names, so a cell, a configuration, a net or a metric is added by adding
+files and entries, never by editing code.
 """
 from __future__ import annotations
 
@@ -29,6 +30,10 @@ CELL_KEYS = {"name", "config", "traffic", "chips", "why"}
 E2E_KEYS = {"name", "unit", "better", "bound", "source"}
 LAYER_KEYS = {"name", "unit", "better", "source", "layer", "moves"}
 READER_KEYS = ("LAYER", "MOVES", "read")
+# what a net's module gives the harness (bench/models/cgan28.py)
+MODEL_KEYS = ("IMAGE", "NUM_CLASSES", "ema_features", "param_template",
+              "init_params", "make_layers", "make_step", "layer_flops",
+              "step_flops_per_sample", "build_trainer")
 
 
 class SpecError(ValueError):
@@ -63,18 +68,46 @@ def reader_path(name: str, root: str = ROOT) -> str:
     return os.path.join(root, "bench", "metrics", f"{name}.py")
 
 
+def model_path(name: str, root: str = ROOT) -> str:
+    return os.path.join(root, "bench", "models", f"{name}.py")
+
+
 def read_json(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
 
 
-def load_reader(name: str, root: str = ROOT):
-    """The per-layer metric's reader module, loaded from its file."""
+def _load_file(module: str, path: str):
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + re.sub(r"\W", "_", name), reader_path(name, root))
+        re.sub(r"\W", "_", module), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_reader(name: str, root: str = ROOT):
+    """The per-layer metric's reader module, loaded from its file."""
+    return _load_file("bench_metric_" + name, reader_path(name, root))
+
+
+def load_model(name: str, root: str = ROOT):
+    """The net's module, loaded from its file."""
+    return _load_file("bench_model_" + name, model_path(name, root))
+
+
+def _check_model(config: str, cfg: dict, root: str) -> None:
+    if "model" not in cfg:
+        raise SpecError(f"config {config}: no model")
+    model = cfg["model"]
+    _name(model, f"config {config}: model")
+    if not os.path.exists(model_path(model, root)):
+        raise SpecError(f"config {config}: model {model} has no module "
+                        f"bench/models/{model}.py")
+    mod = load_model(model, root)
+    gone = [k for k in MODEL_KEYS if not hasattr(mod, k)]
+    if gone:
+        raise SpecError(f"config {config}: model {model} lacks "
+                        + ", ".join(gone))
 
 
 def cell(bench: dict, name: str) -> dict:
@@ -144,6 +177,7 @@ def validate(bench: dict, root: str = ROOT) -> None:
         if cfg.get("source") != c["source"] or cfg.get("reduced") != c["reduced"]:
             raise SpecError(f"config {c['name']}: source and reduced must "
                             "match its file")
+        _check_model(c["name"], cfg, root)
 
     cells = bench["workloads"]
     if not 1 <= len(cells) <= 24:

@@ -5,15 +5,16 @@ change what the benchmark times or checks."""
 import jax
 import pytest
 
-from bench import adapter, population, reference, run
+from bench import adapter, reference, run
+from bench.models import cgan28
 from bench.test_bench_check import SEED, _tiny
 
 
 @pytest.fixture(scope="module")
 def trainer():
     cfg, traffic = _tiny()
-    clients = population.population(traffic, cfg["num_clients"])
-    return run.build_trainer(cfg, traffic, clients, SEED % run.SEED_MOD), cfg
+    clients = run.clients_of(cgan28, cfg, traffic)
+    return cgan28.build_trainer(cfg, traffic, clients, SEED % run.SEED_MOD), cfg
 
 
 def test_trainer_has_every_attribute(trainer):
@@ -26,8 +27,8 @@ def test_trainer_has_every_attribute(trainer):
 
 def test_adam_state_has_moments(trainer):
     tr, cfg = trainer
-    params = reference.init_params(cfg, reference.groups_of(cfg),
-                                   SEED % run.SEED_MOD)
+    params = cgan28.init_params(cfg, reference.groups_of(cfg),
+                                SEED % run.SEED_MOD)
     adapter.set_weights(tr, params)
     now = adapter.trained(tr)
     for key in ("mu", "nu"):

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax import lax
 
-from bench import compare, population, reference, run, spec
+from bench import compare, reference, run, spec
 from repro.core import huscf
 
 LIMITS = spec.read_json(spec.config_path("cgan28-paper100"))["limits"]
@@ -48,7 +48,7 @@ def test_sound_run_is_correct():
 
 def test_control_fails():
     cfg, traffic = _tiny()
-    clients = population.population(traffic, cfg["num_clients"])
+    clients = run.clients_of(spec.load_model(cfg["model"]), cfg, traffic)
     groups = reference.groups_of(cfg)
     seed = SEED % run.SEED_MOD
     n = 4

@@ -2,7 +2,7 @@
 
 Per conv layer, XLA's ``cost_analysis`` counts exactly the products
 that touch neither SAME padding nor a transposed conv's dilation
-zeros, which is what ``flops.layer_flops`` counts: the ratio is 1.
+zeros, which is what ``cgan28.layer_flops`` counts: the ratio is 1.
 For a whole training step of a tiny population (2 groups of 2
 clients, batch 4) XLA also counts the elementwise work the counter
 leaves out (BatchNorm, activations, losses, Adam), and merges the G
@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import pytest
 from jax import lax
 
-from bench import flops, population, run, spec
+from bench import flops, run, spec
+from bench.models import cgan28
 
 DN = ("NHWC", "HWIO", "NHWC")
 
@@ -30,16 +31,9 @@ def _xla_flops(fn, *args):
 
 
 def _conv_layers():
-    cfg = _cfg()
-    out, h = [], cfg["generator"]["fc_out"][0]
-    for i, (cin, cout, k, s) in enumerate(cfg["generator"]["convt"]):
-        out.append(("G", i + 1, h, cin, cout, k, s))
-        h *= s
-    h = cfg["discriminator"]["image"][0]
-    for i, (cin, cout, k, s) in enumerate(cfg["discriminator"]["conv"]):
-        out.append(("D", i, h, cin, cout, k, s))
-        h = -(-h // s)
-    return out
+    # square inputs: (net, layer, h, cin, cout, k, s)
+    return [(net, layer, h, cin, cout, k, s) for net, layer, h, w, cin,
+            cout, k, s in cgan28.conv_layers(_cfg()) if h == w]
 
 
 @pytest.mark.parametrize("net,layer,h,cin,cout,k,s", _conv_layers())
@@ -52,26 +46,24 @@ def test_conv_layer_matches_xla(net, layer, h, cin, cout, k, s):
     else:
         fn = lambda x, w: lax.conv_general_dilated(x, w, (s, s), "SAME",
                                                    dimension_numbers=DN)
-    assert flops.layer_flops(_cfg())[net][layer] == _xla_flops(fn, x, w)
+    assert cgan28.layer_flops(_cfg())[net][layer] == _xla_flops(fn, x, w)
 
 
 def test_dense_layers_match_xla():
     cfg = _cfg()
-    f = flops.layer_flops(cfg)
-    g = cfg["generator"]
-    x = jnp.ones((1, g["z_dim"] + g["num_classes"]))
-    w = jnp.ones((g["z_dim"] + g["num_classes"], 7 * 7 * 256))
-    assert f["G"][0] == _xla_flops(jnp.dot, x, w)
-    d = cfg["discriminator"]
-    assert f["D"][-1] == _xla_flops(jnp.dot, jnp.ones((1, d["fc_in"])),
-                                    jnp.ones((d["fc_in"], 1)))
+    f = cgan28.layer_flops(cfg)
+    shapes = cgan28.layer_shapes(cfg)
+    for net, layer in (("G", 0), ("D", -1)):
+        n_in, n_out = shapes[net][layer]["fc"]["w"]
+        assert f[net][layer] == _xla_flops(jnp.dot, jnp.ones((1, n_in)),
+                                           jnp.ones((n_in, n_out)))
 
 
 def test_published_widths_count():
     # 3 G forwards and 8 D forwards a sample, padding taps left out
-    f = flops.layer_flops(_cfg())
+    f = cgan28.layer_flops(_cfg())
     assert sum(f["G"]) == 148127232 and sum(f["D"]) == 34735104
-    assert flops.step_flops_per_sample(_cfg()) == \
+    assert cgan28.step_flops_per_sample(_cfg()) == \
         3 * sum(f["G"]) + 8 * sum(f["D"])
 
 
@@ -79,12 +71,13 @@ def test_step_flops_against_xla():
     cfg = _cfg()
     cfg.update(num_clients=4, device_cycle=["device1", "device2"], batch=4)
     traffic = dict(spec.read_json(spec.traffic_path("e1")), base_size=18)
-    tr = run.build_trainer(cfg, traffic, population.population(traffic, 4), 0)
+    tr = cgan28.build_trainer(cfg, traffic,
+                              run.clients_of(cgan28, cfg, traffic), 0)
     fn = tr._build_epoch(1)
     cost = fn.lower(tr.state, tr._dataset, tr._train_key, tr._mid_ema,
                     tr._ema_init).compile().cost_analysis()
     xla = (cost[0] if isinstance(cost, list) else cost)["flops"]
-    ours = flops.step_flops_per_sample(cfg) * 4 * cfg["batch"]
+    ours = cgan28.step_flops_per_sample(cfg) * 4 * cfg["batch"]
     # above 1 by the elementwise work only; a fourth G forward counted
     # (1.20) or one D backward missed (0.88) falls outside
     assert 1.0 < xla / ours < 1.08

@@ -7,10 +7,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import reference, run
+from bench import reference, run, spec
+from bench.models import cgan28
 from repro.core.clustering import cluster_activations_jax
 
 NUM_CLIENTS = 100                   # cgan28-paper100
+FEATURES = cgan28.ema_features(spec.read_json(
+    spec.config_path("cgan28-paper100")))
 SEEDS = [615040690, 615040693, 2700000001, 2800000004, 3000000001,
          2147483801]
 
@@ -23,7 +26,7 @@ def stage3():
 @pytest.mark.parametrize("seed", SEEDS)
 def test_program_stage3_finds_planted_partition(stage3, seed):
     seed31 = seed % run.SEED_MOD
-    acts = reference.planted_activations(NUM_CLIENTS, seed31)
+    acts = reference.planted_activations(NUM_CLIENTS, FEATURES, seed31)
     ref_labels, ref_k = reference.cluster(acts)
     assert ref_k == 2 + seed31 % 3
     # the trainer's key for its first clustered round
